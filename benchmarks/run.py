@@ -24,6 +24,9 @@ def main() -> None:
     ap.add_argument("--only", default=None, help="comma-separated bench names")
     ap.add_argument("--json", default=None, help="also write rows as JSON here")
     args = ap.parse_args()
+    from repro import compile_cache
+
+    compile_cache.enable()
 
     only = set(args.only.split(",")) if args.only else None
     print("name,value,unit,notes")

@@ -4,11 +4,14 @@
 """
 import numpy as np
 
+from repro import compile_cache
 from repro.core import algorithms as alg
 from repro.core import ctree as ct
 from repro.core import graph as G
 from repro.core.streaming import AspenStream
 from repro.data.rmat import rmat_edges, symmetrize
+
+compile_cache.enable()  # persistent XLA cache, before the first compile
 
 # --- 1. A C-tree is a compressed purely-functional ordered set ------------
 rng = np.random.default_rng(0)

@@ -16,10 +16,13 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import graph as G
 from repro.core.streaming import AspenStream
 from repro.data.rmat import rmat_edges, symmetrize
 from repro.serve.graph import GraphQueryService
+
+compile_cache.enable()  # persistent XLA cache, before the first compile
 
 # --- 1. A graph, a stream, a service ---------------------------------------
 n = 1 << 10

@@ -12,12 +12,15 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import flat_graph as fg
 from repro.core import graph as G
 from repro.core.streaming import AspenStream, make_update_stream, run_concurrent
 from repro.core.traversal import make_engine
 from repro.core.traversal import algorithms as talg
 from repro.data.rmat import rmat_edges, symmetrize
+
+compile_cache.enable()  # persistent XLA cache, before the first compile
 
 n = 4096
 edges = symmetrize(rmat_edges(12, 80_000, seed=0))

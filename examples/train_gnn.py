@@ -16,12 +16,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.core import flat_graph as fg
 from repro.data.pipeline import NeighborSampler, power_law_graph
 from repro.dist.fault_tolerance import ResumableRun
 from repro.models.gnn import graphsage
 from repro.optim import adamw
 from repro.train import train_step as TS
+
+compile_cache.enable()  # persistent XLA cache, before the first compile
 
 
 def main():

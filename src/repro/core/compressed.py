@@ -196,7 +196,7 @@ def _encode_adaptive_impl(
     ovf_pos = jnp.take_along_axis(pos_all, order, axis=1)
     ovf_add = jnp.take_along_axis(jnp.where(esc, deltas, 0), order, axis=1)
     wide_i = wide.astype(jnp.int32)
-    hi_idx = jnp.cumsum(wide_i) - 1  # compacted row per wide chunk
+    hi_idx = jnp.cumsum(wide_i, dtype=jnp.int32) - 1  # compacted row per wide chunk
     target = jnp.where(wide, hi_idx, hi_cap)
     hi = (
         jnp.zeros((hi_cap, CHUNK), jnp.int8)
@@ -237,7 +237,7 @@ def adaptive_deltas(c: ChunkedStream) -> jax.Array:
         # no wide chunk can exist without spilling; lane is exact
         return lane
     idx = jnp.clip(
-        jnp.cumsum(c.wide.astype(jnp.int32), axis=-1) - 1, 0, H - 1
+        jnp.cumsum(c.wide, axis=-1, dtype=jnp.int32) - 1, 0, H - 1
     )
     hi_g = jnp.take_along_axis(c.hi.astype(jnp.int32), idx[..., None], axis=-2)
     return jnp.where(c.wide[..., None], hi_g * 256 + (lane & 0xFF), lane)
@@ -250,7 +250,7 @@ def decode_rows(c: ChunkedStream) -> jax.Array:
     the fused-decode contract (the Pallas half lives in
     ``kernels/delta_decode.py`` / ``kernels/segment_reduce.py``)."""
     d = adaptive_deltas(c) if c.hi is not None else c.deltas.astype(jnp.int32)
-    base = c.anchors[..., None] + jnp.cumsum(d, axis=-1)
+    base = c.anchors[..., None] + jnp.cumsum(d, axis=-1, dtype=jnp.int32)
     cols = jax.lax.broadcasted_iota(jnp.int32, c.deltas.shape, c.deltas.ndim - 1)
     corr = jnp.sum(
         jnp.where(cols[..., None] >= c.ovf_pos[..., None, :], c.ovf_add[..., None, :], 0),

@@ -167,7 +167,7 @@ def head_mask(t: FlatCTree, b: int, seed: int) -> jax.Array:
 @functools.partial(jax.jit, static_argnums=(1, 2))
 def chunk_ids(t: FlatCTree, b: int, seed: int) -> jax.Array:
     """chunk id per slot; prefix = 0, tail of i-th head = i+1."""
-    return jnp.cumsum(head_mask(t, b, seed).astype(jnp.int32))
+    return jnp.cumsum(head_mask(t, b, seed), dtype=jnp.int32)
 
 
 def num_heads(t: FlatCTree, b: int, seed: int) -> int:
@@ -193,7 +193,7 @@ def _compact(
     """Scatter kept values to the front of a fresh pool (associated
     values, when present, ride the same permutation)."""
     sent = sentinel_for(values.dtype)
-    pos = jnp.cumsum(keep.astype(jnp.int32)) - 1
+    pos = jnp.cumsum(keep, dtype=jnp.int32) - 1
     pos = jnp.where(keep, pos, out_cap)  # dropped via OOB
     out = jnp.full((out_cap,), sent, dtype=values.dtype)
     out = out.at[pos].set(values, mode="drop")
@@ -267,7 +267,7 @@ def union_merge(t: FlatCTree, batch: FlatCTree, out_cap: int) -> FlatCTree:
     ia = jnp.minimum(jnp.searchsorted(a, b), ca - 1)
     dup_b = (a[ia] == b) & valid_b
     keep_b = valid_b & ~dup_b
-    kb_excl = jnp.cumsum(keep_b.astype(jnp.int32)) - keep_b  # exclusive prefix
+    kb_excl = jnp.cumsum(keep_b, dtype=jnp.int32) - keep_b  # exclusive prefix
 
     # positions
     ra = jnp.searchsorted(b, a)  # #b-entries < a[i] (valid b only: pad=max)

@@ -50,15 +50,22 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import compressed as cz
 from .flat_ctree import sentinel_for
 
-try:  # jax >= 0.6 exposes shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
+
+def shard_map(f, *, mesh, in_specs, out_specs):
+    """The package's one ``shard_map`` (``jax.shard_map``).
+
+    Replication checking is off: every body that returns a replicated
+    (``P()``) output makes it so with an explicit collective (psum, pmax,
+    pmin), which is the contract the sharded engine is written to."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
+
 
 SENT = sentinel_for(jnp.int64)
 
@@ -123,11 +130,16 @@ def from_array(
         if wdata is not None:
             wdata[s, : chunk.size] = w[s * per : (s + 1) * per]
     lo[0] = np.iinfo(np.int64).min
+    # shard rows live on their mesh devices from the start (not all on
+    # device 0 until the first shard_map'd step moves them)
+    mesh = pool_mesh(n_shards)
+    rows = NamedSharding(mesh, P("shard"))
+    rows2 = NamedSharding(mesh, P("shard", None))
     return ShardedPool(
-        jnp.asarray(data),
-        jnp.asarray(n),
-        jnp.asarray(lo),
-        None if wdata is None else jnp.asarray(wdata),
+        jax.device_put(data, rows2),
+        jax.device_put(n, rows),
+        jax.device_put(lo, rows),
+        None if wdata is None else jax.device_put(wdata, rows2),
     )
 
 
@@ -188,7 +200,7 @@ def _local_merge(
     ia = jnp.minimum(jnp.searchsorted(pool_row, b), cap - 1)
     dup_b = (pool_row[ia] == b) & valid_b
     keep_b = valid_b & ~dup_b
-    kb_excl = jnp.cumsum(keep_b.astype(jnp.int32)) - keep_b
+    kb_excl = jnp.cumsum(keep_b, dtype=jnp.int32) - keep_b
     ra = jnp.searchsorted(b, pool_row)
     kept_below_a = jnp.where(
         ra > 0,
@@ -249,13 +261,13 @@ def make_insert_step(mesh: Mesh, axis_names: Tuple[str, ...]):
 
         return jax.vmap(row)(data, n, vals, lo, hi)
 
-    step_plain = _shard_map(
+    step_plain = shard_map(
         local_plain,
         mesh=mesh,
         in_specs=(spec_sharded2, spec_sharded, spec_sharded, spec_sharded, P()),
         out_specs=(spec_sharded2, spec_sharded),
     )
-    step_vals = _shard_map(
+    step_vals = shard_map(
         local_vals,
         mesh=mesh,
         in_specs=(
@@ -304,7 +316,7 @@ def make_delete_step(mesh: Mesh, axis_names: Tuple[str, ...]):
             idx = jnp.minimum(jnp.searchsorted(batch, drow), kcap - 1)
             hit = (batch[idx] == drow) & (drow != SENT)
             keep = (jnp.arange(cap) < nrow) & ~hit
-            pos = jnp.cumsum(keep.astype(jnp.int32)) - 1
+            pos = jnp.cumsum(keep, dtype=jnp.int32) - 1
             pos = jnp.where(keep, pos, cap)
             out = jnp.full((cap,), SENT, jnp.int64).at[pos].set(drow, mode="drop")
             n_new = keep.sum().astype(jnp.int32)
@@ -325,13 +337,13 @@ def make_delete_step(mesh: Mesh, axis_names: Tuple[str, ...]):
     def local_vals(data, n, vals, batch):
         return _rows(data, n, batch, vals)
 
-    step_plain = _shard_map(
+    step_plain = shard_map(
         local_plain,
         mesh=mesh,
         in_specs=(spec_sharded2, spec_sharded, P()),
         out_specs=(spec_sharded2, spec_sharded),
     )
-    step_vals = _shard_map(
+    step_vals = shard_map(
         local_vals,
         mesh=mesh,
         in_specs=(spec_sharded2, spec_sharded, spec_sharded2, P()),
@@ -514,14 +526,16 @@ def pool_mesh(n_shards: int) -> Mesh:
     """A 1-axis mesh whose size divides ``n_shards``: all devices when
     possible, else the largest divisor of n_shards that fits (a 1-device
     run degenerates to a single-chip mesh, which is still correct —
-    every collective becomes a local no-op)."""
+    every collective becomes a local no-op).  The axis is an automatic
+    (GSPMD) axis: the explicit-sharding default of ``jax.make_mesh`` would
+    type every vmapped row op of ``shard_aux`` with the shard axis."""
     nd = jax.device_count()
     size = 1
     for d in range(min(n_shards, nd), 0, -1):
         if n_shards % d == 0:
             size = d
             break
-    return jax.make_mesh((size,), ("shard",))
+    return Mesh(np.array(jax.devices()[:size]), ("shard",))
 
 
 def graph_from_edges(

@@ -117,6 +117,40 @@ class UpdateQueue:
             self._cond.notify_all()
             return True
 
+    def put_many(
+        self,
+        edges: np.ndarray,
+        *,
+        delete: bool = False,
+        block: bool = True,
+        timeout: Optional[float] = None,
+    ) -> int:
+        """Enqueue a (k, 2) batch of updates as one unit (in pieces of at
+        most ``maxsize``), so a writer drain never splits it into several
+        publishes of odd sizes.  Returns how many were enqueued; a piece
+        that finds no room (``block=False``, or after ``timeout``) is
+        rejected whole."""
+        rows = [(int(s), int(d), bool(delete), None)
+                for s, d in np.asarray(edges, dtype=np.int64).reshape(-1, 2)]
+        step = max(len(rows) if self.maxsize is None else self.maxsize, 1)
+        done = 0
+        for i in range(0, len(rows), step):
+            piece = rows[i:i + step]
+            with self._cond:
+                if self.maxsize is not None:
+                    room = lambda: len(self._q) + len(piece) <= self.maxsize  # noqa: E731
+                    if (not block and not room()) or not self._cond.wait_for(
+                        room, timeout=timeout
+                    ):
+                        self._rejected += len(piece)
+                        continue
+                self._q.extend(piece)
+                self._enqueued += len(piece)
+                self._high_water = max(self._high_water, len(self._q))
+                self._cond.notify_all()
+                done += len(piece)
+        return done
+
     def pop_batch(self, k: int) -> list:
         """Dequeue up to ``k`` pending updates (possibly empty; never
         blocks) in FIFO order."""
@@ -194,6 +228,7 @@ class AspenStream:
         donate_buffers: bool = False,
         n_shards: Optional[int] = None,
         compressed: bool = False,
+        edge_capacity: Optional[int] = None,
     ):
         """``mirror=True`` (default, = ``"flat"``) maintains the resident
         FlatGraph alongside the tree; ``mirror="sharded"`` maintains a
@@ -213,7 +248,13 @@ class AspenStream:
         so the RESIDENT state is always a few bytes/edge, and
         ``engine()`` serves the matching compressed engine.  Donation is
         unavailable on compressed mirrors (the merge's uncompressed pool
-        is a transient, never a reusable buffer)."""
+        is a transient, never a reusable buffer).
+
+        ``edge_capacity`` floors the flat mirror's pool capacity (default:
+        the power of two above the edge count).  Capacity is a static
+        shape of every compiled query, so a stream that will grow past
+        the default reserves the room up front; growth beyond it still
+        re-quantizes to the next power of two (and recompiles)."""
         g0 = initial if initial is not None else G.empty(b, seed)
         kind = {True: MIRROR, False: None}.get(mirror, mirror)
         if kind not in (None, MIRROR, SHARDED_MIRROR):
@@ -226,6 +267,7 @@ class AspenStream:
             raise ValueError("donate_buffers is unavailable on compressed mirrors")
         self._mirror_kind = kind
         self._mirror_enabled = kind is not None
+        self._edge_capacity = edge_capacity
         self._compressed = compressed
         self._donate = donate_buffers
         if kind == SHARDED_MIRROR:
@@ -277,13 +319,12 @@ class AspenStream:
                 pass
 
     # -- mirror maintenance -------------------------------------------------
-    @staticmethod
-    def _flat_from_tree(g: G.Graph):
+    def _flat_from_tree(self, g: G.Graph):
         """Full FlatGraph rebuild (O(m) host): construction and the rare
         vertex-set operations; edge batches take the incremental path."""
         from .traversal import flat_graph_of
 
-        return flat_graph_of(G.flat_snapshot(g))
+        return flat_graph_of(G.flat_snapshot(g), edge_capacity=self._edge_capacity)
 
     def _mirror_from_tree(self, g: G.Graph):
         """Full mirror rebuild in the stream's configured representation.
@@ -573,6 +614,24 @@ class AspenStream:
             lambda m, g_old, g_new: self._apply_delete(m, edges),
             delta=Delta(dels=edges),
         )
+
+    def rebalance(self):
+        """Publish the current graph with its sharded mirror redistributed
+        to equal per-shard counts: the compaction ``insert_edges`` takes
+        by itself on skew or overflow, on demand.  The graph, and so every
+        answer, is unchanged (an empty delta)."""
+        if self._mirror_kind != SHARDED_MIRROR:
+            raise ValueError("rebalance needs mirror='sharded'")
+        from . import sharded_pool as sp
+
+        def mirror_fn(m, g_old, g_new):
+            if isinstance(m, sp.CompressedShardedGraph):
+                return sp.CompressedShardedGraph(
+                    sp.rebalance_compressed(m.pool, m.n), m.n
+                )
+            return sp.ShardedGraph(sp.rebalance(m.pool), m.n)
+
+        return self._publish(lambda g: g, mirror_fn, delta=Delta())
 
     def insert_vertices(self, vs: np.ndarray):
         # vertex-set ops are control-plane-rare: the mirror takes the

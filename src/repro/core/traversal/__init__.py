@@ -162,15 +162,18 @@ def sharded_graph_of_flat(g, n_shards: int | None = None):
     )
 
 
-def flat_graph_of(snap):
+def flat_graph_of(snap, edge_capacity: int | None = None):
     """FlatSnapshot -> FlatGraph (host-side O(m) CSR rebuild; weighted
     snapshots carry their per-edge values into the pool's value array).
+    ``edge_capacity`` is a floor on the pool capacity (default: the
+    power of two above the edge count).
 
     This is the *fallback* substrate conversion — streams keep a
     resident mirror precisely so queries never pay this per version
     (``FLAT_REBUILDS`` counts how often anyone still does)."""
     import numpy as np
 
+    from ..flat_ctree import grown_capacity
     from ..flat_graph import from_edges
 
     FLAT_REBUILDS.bump()
@@ -181,7 +184,12 @@ def flat_graph_of(snap):
         if getattr(snap, "weighted", False)
         else None
     )
-    return from_edges(snap.n, np.stack([srcs, nbrs], axis=1), weights=weights)
+    if edge_capacity is not None:
+        edge_capacity = max(edge_capacity, grown_capacity(nbrs.size))
+    return from_edges(
+        snap.n, np.stack([srcs, nbrs], axis=1),
+        edge_capacity=edge_capacity, weights=weights,
+    )
 
 
 _flat_graph_of = flat_graph_of  # backward-compatible alias

@@ -64,6 +64,7 @@ versus the float64 numpy engine is to float32 tolerance by default.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
@@ -243,18 +244,89 @@ def engine_aux(g: FlatGraph) -> EngineAux:
 # ---------------------------------------------------------------------------
 
 
+_SCAN_BLOCK = 128
+
+
+def _shift_right(x: jax.Array, s: int, fill) -> jax.Array:
+    """``x`` moved ``s`` places along the last axis; ``fill`` enters."""
+    pad = jnp.full(x.shape[:-1] + (s,), fill, x.dtype)
+    return jnp.concatenate([pad, x[..., : x.shape[-1] - s]], axis=-1)
+
+
+def _hs_scan(v, f, combine, ident):
+    """Hillis–Steele segmented inclusive scan along the last axis:
+    (value, start-flag) pairs under the resetting operator
+    ``(x, y) -> (y.f ? y.v : combine(x.v, y.v), x.f | y.f)``.  Returns
+    the scanned values and the prefix-OR of the flags (``f=None``: an
+    unsegmented scan)."""
+    s = 1
+    while s < v.shape[-1]:
+        shifted = combine(_shift_right(v, s, ident), v)
+        if f is None:
+            v = shifted
+        else:
+            v = jnp.where(f, v, shifted)
+            f = f | _shift_right(f, s, False)
+        s *= 2
+    return v, f
+
+
+def _blocked_scan(x, flags, combine, ident):
+    """Inclusive scan along the last axis, segmented when ``flags`` (a
+    bool vector of segment starts over that axis) is given.  Two levels:
+    a scan inside each ``_SCAN_BLOCK``-lane block, then one over the
+    block carries.  Shifted adds compile in seconds at any size, where
+    ``jnp.cumsum`` (a reduce-window) and ``lax.associative_scan`` take the
+    TPU compiler tens of seconds to minutes on a 2^20..2^22 axis.  A
+    segmented sum never runs across a segment boundary, so integer and
+    small-float segment sums are exact."""
+    *lead, L = x.shape
+    blk = math.gcd(L, _SCAN_BLOCK)
+    f = None if flags is None else flags.reshape(L // blk, blk)
+    v, f = _hs_scan(x.reshape(*lead, L // blk, blk), f, combine, ident)
+    carry, _ = _hs_scan(v[..., -1], None if f is None else f[..., -1], combine, ident)
+    carry = _shift_right(carry, 1, ident)[..., None]  # exclusive over blocks
+    out = combine(carry, v)
+    return (out if f is None else jnp.where(f, v, out)).reshape(x.shape)
+
+
+def _cumsum(x: jax.Array) -> jax.Array:
+    """Inclusive prefix sum along the last axis in ``x``'s own dtype."""
+    return _blocked_scan(x, None, jnp.add, jnp.zeros((), x.dtype))
+
+
+def _take_mask(f_b: jax.Array, idx: jax.Array) -> jax.Array:
+    """``f_b[:, idx]`` for a (B, n) bool mask, gathered as int32: the TPU
+    compiler gives a bool gather of a 2^23-slot index over a gigabyte of
+    temporaries (9 GiB in a B=4 pull round), an int32 one none."""
+    return f_b.astype(jnp.int32)[:, idx] != 0
+
+
+def _nonzero_i32(mask: jax.Array, size: int, fill_value: int) -> jax.Array:
+    """``jnp.nonzero(mask, size=size, fill_value=fill_value)[0]`` for a
+    1-D mask, computed in int32 (the same rank/bincount/cumsum method).
+    Under x64, ``jnp.nonzero`` scans in int64, which the TPU emulates on
+    pairs of 32-bit words and which outgrows its scoped VMEM at n=2^17."""
+    rank = _cumsum(mask.astype(jnp.int32))
+    counts = jnp.zeros(size, jnp.int32).at[rank].add(1, mode="drop")
+    ids = _cumsum(counts)
+    return jnp.where(jnp.arange(size, dtype=jnp.int32) < rank[-1], ids, fill_value)
+
+
 def _sparse_expand(offsets, keys, U, n: int, ids_budget: int, edge_budget: int):
     """Fixed-shape push expansion of one bool[n] frontier:
     (us, vs, ev, eidx) edge lanes where ``ev`` masks the padded tail
     and edges naming nonexistent destination vertices; ``eidx`` is each
     lane's pool slot (for gathering per-edge values alongside)."""
-    ids_raw = jnp.nonzero(U, size=ids_budget, fill_value=n)[0]
+    ids_raw = _nonzero_i32(U, ids_budget, n)
     vid = ids_raw < n
     ids = jnp.where(vid, ids_raw, 0).astype(jnp.int32)
-    starts = offsets[ids].astype(jnp.int64)
-    degs = jnp.where(vid, (offsets[ids + 1] - offsets[ids]), 0).astype(jnp.int64)
-    cum = jnp.cumsum(degs)
-    j = jnp.arange(edge_budget, dtype=jnp.int64)
+    # int32 edge arithmetic: slot ids stay below the pool capacity, and
+    # int64 scans are emulated on the TPU (and outgrow its scoped VMEM)
+    starts = offsets[ids].astype(jnp.int32)
+    degs = jnp.where(vid, (offsets[ids + 1] - offsets[ids]), 0).astype(jnp.int32)
+    cum = _cumsum(degs)
+    j = jnp.arange(edge_budget, dtype=jnp.int32)
     seg = jnp.searchsorted(cum, j, side="right")
     seg = jnp.clip(seg, 0, ids_budget - 1)
     prev = jnp.where(seg > 0, cum[jnp.maximum(seg - 1, 0)], 0)
@@ -391,46 +463,43 @@ def _reduce_msgs_batch(values_b, src_by_dst, valid_by_dst, dtype=jnp.float32):
 # ---------------------------------------------------------------------------
 
 
+def _seg_reduce(msg_b, bounds, combine, ident, empty):
+    """(B, cap) messages + int32[S+1] segment bounds -> (B, S): each
+    segment's scan value at its last slot, ``empty`` for empty ones."""
+    cap = msg_b.shape[1]
+    starts = jnp.zeros(cap, dtype=bool).at[bounds[:-1]].set(True, mode="drop")
+    scanned = _blocked_scan(msg_b, starts, combine, ident)
+    ends = jnp.clip(bounds[1:] - 1, 0, cap - 1)
+    return jnp.where(bounds[1:] > bounds[:-1], scanned[:, ends], empty)
+
+
+def _lowest(dtype):
+    return -jnp.inf if jnp.issubdtype(dtype, jnp.floating) else jnp.iinfo(dtype).min
+
+
+def _highest(dtype):
+    return jnp.inf if jnp.issubdtype(dtype, jnp.floating) else jnp.iinfo(dtype).max
+
+
 def _segsum_rows(msg_b: jax.Array, bounds: jax.Array) -> jax.Array:
     """Row-wise segmented sum over a contiguously-segmented axis:
     (B, cap) messages + int32[S+1] segment bounds -> (B, S) sums.
 
-    cumsum + boundary-difference instead of a scatter: XLA scatters
+    A segmented scan and one gather instead of a scatter: XLA scatters
     serialize per element (they are the batched drivers' bottleneck on
-    CPU), while a row cumsum and two gathers vectorize on any backend.
-    The pool IS the segmentation: src-major segments are ``g.offsets``,
-    dst-major segments are ``aux.dst_offsets``."""
-    csum = jnp.cumsum(msg_b, axis=1)
-    z = jnp.zeros((msg_b.shape[0], 1), csum.dtype)
-    padded = jnp.concatenate([z, csum], axis=1)
-    return padded[:, bounds[1:]] - padded[:, bounds[:-1]]
+    CPU).  The pool IS the segmentation: src-major segments are
+    ``g.offsets``, dst-major segments are ``aux.dst_offsets``."""
+    zero = jnp.zeros((), msg_b.dtype)
+    return _seg_reduce(msg_b, bounds, jnp.add, zero, zero)
 
 
 def _segmin_rows(msg_b: jax.Array, bounds: jax.Array) -> jax.Array:
     """Row-wise segmented MIN over a contiguously-segmented axis:
     (B, cap) messages + int32[S+1] segment bounds -> (B, S) minima
-    (+inf for empty segments).
-
-    min has no inverse, so the cumsum/boundary-difference trick of
-    ``_segsum_rows`` does not apply; instead this is the classic
-    *segmented scan*: an ``associative_scan`` over (value, start-flag)
-    pairs whose operator resets at segment starts, then one gather of
-    each segment's last position.  Still scatter-free and one
-    log-depth pass — the (min, +) analogue of the pull rounds'
-    row-cumsum, used by ``sssp_batch``."""
-    cap = msg_b.shape[1]
-    flags = jnp.zeros(cap, dtype=bool).at[bounds[:-1]].set(True, mode="drop")
-    flags_b = jnp.broadcast_to(flags, msg_b.shape)
-
-    def op(x, y):
-        mx, fx = x
-        my, fy = y
-        return jnp.where(fy, my, jnp.minimum(mx, my)), fx | fy
-
-    scanned, _ = jax.lax.associative_scan(op, (msg_b, flags_b), axis=1)
-    inf = jnp.asarray(jnp.inf, msg_b.dtype)
-    ends = jnp.clip(bounds[1:] - 1, 0, cap - 1)
-    return jnp.where(bounds[1:] > bounds[:-1], scanned[:, ends], inf)
+    (+inf for empty segments) — the (min, +) analogue of the pull
+    rounds' segmented sum, used by ``sssp_batch``."""
+    hi = jnp.asarray(_highest(msg_b.dtype), msg_b.dtype)
+    return _seg_reduce(msg_b, bounds, jnp.minimum, hi, hi)
 
 
 @functools.partial(jax.jit, static_argnames=("ids_budget", "edge_budget"))
@@ -473,7 +542,7 @@ def bfs_batch(
         return jax.vmap(one)(f_b)
 
     def pull(f_b):
-        msg = (f_b[:, aux.src_by_dst] & aux.valid_by_dst[None, :]).astype(jnp.int32)
+        msg = (_take_mask(f_b, aux.src_by_dst) & aux.valid_by_dst[None, :]).astype(jnp.int32)
         return _segsum_rows(msg, aux.dst_offsets) > 0
 
     def cond(carry):
@@ -494,21 +563,9 @@ def bfs_batch(
 def _segmax_rows(msg_b: jax.Array, bounds: jax.Array) -> jax.Array:
     """Row-wise segmented MAX over a contiguously-segmented axis:
     (B, cap) messages + int32[S+1] segment bounds -> (B, S) maxima
-    (-1 for empty segments).  The (max) twin of ``_segmin_rows`` —
-    same segmented associative_scan, no scatter."""
-    cap = msg_b.shape[1]
-    flags = jnp.zeros(cap, dtype=bool).at[bounds[:-1]].set(True, mode="drop")
-    flags_b = jnp.broadcast_to(flags, msg_b.shape)
-
-    def op(x, y):
-        mx, fx = x
-        my, fy = y
-        return jnp.where(fy, my, jnp.maximum(mx, my)), fx | fy
-
-    scanned, _ = jax.lax.associative_scan(op, (msg_b, flags_b), axis=1)
-    neg = jnp.asarray(-1, msg_b.dtype)
-    ends = jnp.clip(bounds[1:] - 1, 0, cap - 1)
-    return jnp.where(bounds[1:] > bounds[:-1], scanned[:, ends], neg)
+    (-1 for empty segments).  The (max) twin of ``_segmin_rows``."""
+    lo = jnp.asarray(_lowest(msg_b.dtype), msg_b.dtype)
+    return _seg_reduce(msg_b, bounds, jnp.maximum, lo, jnp.asarray(-1, msg_b.dtype))
 
 
 def _parents_pass(g: FlatGraph, aux: EngineAux, depths: jax.Array) -> jax.Array:
@@ -568,7 +625,7 @@ def bc_batch(
     def fbody(carry):
         f, sig, dep, d = carry
         w = jnp.where(
-            f[:, aux.src_by_dst] & aux.valid_by_dst[None, :],
+            _take_mask(f, aux.src_by_dst) & aux.valid_by_dst[None, :],
             sig[:, aux.src_by_dst],
             jnp.zeros((), float_dtype),
         )
@@ -651,7 +708,7 @@ def _bellman_ford(
     def pull(args):
         f_b, d_b = args
         msg = jnp.where(
-            f_b[:, aux.src_by_dst] & aux.valid_by_dst[None, :],
+            _take_mask(f_b, aux.src_by_dst) & aux.valid_by_dst[None, :],
             d_b[:, aux.src_by_dst] + w_by_dst[None, :],
             inf,
         )

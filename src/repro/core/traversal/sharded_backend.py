@@ -61,10 +61,10 @@ from ..sharded_pool import (
     ShardAux,
     ShardedGraph,
     _decompress_pool_impl,
-    _shard_map,
     graph_num_edges,
     pool_mesh,
     shard_aux,
+    shard_map,
 )
 from .base import DENSE_THRESHOLD_DENOM, TRACES, TraversalEngine
 from .jax_backend import (
@@ -75,6 +75,7 @@ from .jax_backend import (
     _segmin_rows,
     _segsum_rows,
     _sparse_expand,
+    _take_mask,
 )
 
 AXIS = "shard"
@@ -239,9 +240,7 @@ def _sharded_edge_map_step(
 
         args = (offsets, keys, src_c, dst_c, evalid, degrees, m, U, state)
         specs = (_SPEC2,) * 6 + (P(), P(), P())
-    return _shard_map(
-        local, mesh=mesh, in_specs=specs, out_specs=(P(), P()), check_rep=False
-    )(*args)
+    return shard_map(local, mesh=mesh, in_specs=specs, out_specs=(P(), P()))(*args)
 
 
 # ---------------------------------------------------------------------------
@@ -284,20 +283,18 @@ def _sharded_reduce_batch(
     """out[b, v] = sum_{u->v} w(u, v) * values[b, u] over all shards."""
     n_pad = _round_up(max(n, 1), mesh.shape[AXIS])
     if weighted:
-        out = _shard_map(
+        out = shard_map(
             lambda s, v, b, w, x: _reduce_partial(s, v, b, w, x, n_pad, dtype),
             mesh=mesh,
             in_specs=(_SPEC2, _SPEC2, _SPEC2, _SPEC2, P()),
             out_specs=P(None, AXIS),
-            check_rep=False,
         )(src_by_dst, valid_by_dst, dst_offsets, w_by_dst, values_b)
     else:
-        out = _shard_map(
+        out = shard_map(
             lambda s, v, b, x: _reduce_partial(s, v, b, None, x, n_pad, dtype),
             mesh=mesh,
             in_specs=(_SPEC2, _SPEC2, _SPEC2, P()),
             out_specs=P(None, AXIS),
-            check_rep=False,
         )(src_by_dst, valid_by_dst, dst_offsets, values_b)
     return out[:, :n]
 
@@ -365,7 +362,7 @@ def bfs_batch_sharded(
 
         def pull(f_b):
             def one_row(srow, vrow, brow):
-                msg = (f_b[:, srow] & vrow[None, :]).astype(jnp.int32)
+                msg = (_take_mask(f_b, srow) & vrow[None, :]).astype(jnp.int32)
                 return _segsum_rows(msg, brow)
 
             loc = jax.vmap(one_row)(sbd, vbd, doff).sum(axis=0)
@@ -403,12 +400,11 @@ def bfs_batch_sharded(
         parents = jnp.where(depths == 0, vid, jnp.where(depths > 0, cand, -1))
         return parents, depths
 
-    return _shard_map(
+    return shard_map(
         local,
         mesh=mesh,
         in_specs=(_SPEC2,) * 9 + (P(), P()),
         out_specs=(P(), P()),
-        check_rep=False,
     )(
         offsets, keys, src_c, dst_c, evalid, degrees,
         src_by_dst, valid_by_dst, dst_offsets, m, sources,
@@ -458,7 +454,7 @@ def bc_batch_sharded(
 
             def one_row(srow, vrow, brow):
                 w = jnp.where(
-                    f[:, srow] & vrow[None, :],
+                    _take_mask(f, srow) & vrow[None, :],
                     sig[:, srow],
                     jnp.zeros((), float_dtype),
                 )
@@ -497,12 +493,11 @@ def bc_batch_sharded(
         )
         return dep.at[lane, src].set(0.0)
 
-    return _shard_map(
+    return shard_map(
         local,
         mesh=mesh,
         in_specs=(_SPEC2,) * 7 + (P(),),
         out_specs=P(),
-        check_rep=False,
     )(offsets, src_c, dst_c, evalid, src_by_dst, valid_by_dst, dst_offsets, sources)
 
 
@@ -557,7 +552,7 @@ def _sharded_bellman_ford(
 
         def one_row(srow, vrow, brow, wrow):
             msg = jnp.where(
-                f_b[:, srow] & vrow[None, :],
+                _take_mask(f_b, srow) & vrow[None, :],
                 d_b[:, srow] + wrow[None, :],
                 inf,
             )
@@ -647,9 +642,7 @@ def sssp_batch_sharded(
         args = (offsets, keys, src_c, dst_c, evalid, degrees, src_by_dst,
                 valid_by_dst, dst_offsets, m, sources)
         specs = (_SPEC2,) * 9 + (P(), P())
-    return _shard_map(
-        local, mesh=mesh, in_specs=specs, out_specs=P(), check_rep=False
-    )(*args)
+    return shard_map(local, mesh=mesh, in_specs=specs, out_specs=P())(*args)
 
 
 @functools.partial(
@@ -712,9 +705,7 @@ def sssp_batch_sharded_from(
         args = (offsets, keys, src_c, dst_c, evalid, degrees, src_by_dst,
                 valid_by_dst, dst_offsets, m, dist0, frontier0)
         specs = (_SPEC2,) * 9 + (P(), P(), P())
-    return _shard_map(
-        local, mesh=mesh, in_specs=specs, out_specs=P(), check_rep=False
-    )(*args)
+    return shard_map(local, mesh=mesh, in_specs=specs, out_specs=P())(*args)
 
 
 # ---------------------------------------------------------------------------
@@ -1300,20 +1291,18 @@ def _sharded_reduce_batch_compressed(
         )
 
     if weighted:
-        out = _shard_map(
+        out = shard_map(
             local,
             mesh=mesh,
             in_specs=stream_specs + (P(AXIS), _SPEC2, _SPEC2, P()),
             out_specs=P(None, AXIS),
-            check_rep=False,
         )(*stream, m_valid, dst_offsets, w_by_dst, values_b)
     else:
-        out = _shard_map(
+        out = shard_map(
             local,
             mesh=mesh,
             in_specs=stream_specs + (P(AXIS), _SPEC2, P()),
             out_specs=P(None, AXIS),
-            check_rep=False,
         )(*stream, m_valid, dst_offsets, values_b)
     return out[:, :n]
 

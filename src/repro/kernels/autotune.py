@@ -22,13 +22,20 @@ Design (DESIGN.md §12):
   load, corruption-tolerant).  Unset, the table is process-local only —
   the library never writes outside paths the user named.
 * **Sweeping** runs real timings over ``CANDIDATES[kernel]`` and is OFF
-  unless the backend is a real TPU or ``REPRO_AUTOTUNE=1`` forces it
-  (interpret-mode timings on CPU measure the emulator, not the kernel —
-  still useful as a smoke of the sweep machinery, which is why the env
-  override exists).  With sweeping off, a cache miss returns
-  ``DEFAULTS[kernel]``.  Invalidation is by key: a new jax backend or a
-  different shape bucket is a different entry; bump ``TABLE_VERSION`` to
-  invalidate a persisted table wholesale.
+  unless ``REPRO_AUTOTUNE=1`` asks for it, on any backend (interpret-mode
+  timings on CPU measure the emulator, not the kernel — useful only as a
+  smoke of the sweep machinery).  A sweep never runs while dispatch is
+  under a trace (``traced=True``): there the thunks would return tracers
+  and time the tracing, so a miss returns ``DEFAULTS[kernel]``.  With
+  sweeping off, a miss returns ``DEFAULTS[kernel]`` too.  A sweep in
+  which every candidate fails raises with the last error.  Invalidation
+  is by key: a new jax backend or a different shape bucket is a
+  different entry; bump ``TABLE_VERSION`` to invalidate a persisted
+  table wholesale.
+
+Every entry of ``DEFAULTS`` and ``CANDIDATES`` compiles for the TPU
+(tests/test_chip_compile.py): the chunked kernels' edge block holds 32
+whole chunk rows, the int8 sublane tile.
 
 Callers pass a ``sweep_fn(params) -> thunk`` factory building the kernel
 launch on synthetic inputs of the real shape; ``sweep`` times each
@@ -50,14 +57,15 @@ TABLE_VERSION = 1
 DEFAULTS: Dict[str, Dict[str, int]] = {
     "segment_sum": {"edge_block": 512, "dst_block": 128},
     "segment_sum_weighted": {"edge_block": 512, "dst_block": 128},
-    "segment_sum_chunked": {"edge_block": 512, "dst_block": 128},
-    "segment_sum_weighted_chunked": {"edge_block": 512, "dst_block": 128},
+    "segment_sum_chunked": {"edge_block": 4096, "dst_block": 128},
+    "segment_sum_weighted_chunked": {"edge_block": 4096, "dst_block": 128},
     "spmm": {"row_tile": 128, "col_tile": 128},
 }
 
 # Small grids on purpose: every candidate costs a compile during a sweep.
 # edge/dst blocks stay multiples of compressed.CHUNK (128) so the chunked
-# kernels' whole-chunks-per-block invariant holds for every candidate.
+# kernels' whole-chunks-per-block invariant holds for every candidate, and
+# chunked edge blocks are multiples of 32 rows * 128 (the int8 tile).
 CANDIDATES: Dict[str, List[Dict[str, int]]] = {
     "segment_sum": [
         {"edge_block": e, "dst_block": d}
@@ -66,7 +74,7 @@ CANDIDATES: Dict[str, List[Dict[str, int]]] = {
     ],
     "segment_sum_chunked": [
         {"edge_block": e, "dst_block": d}
-        for e in (256, 512, 1024)
+        for e in (4096, 8192)
         for d in (128, 256)
     ],
     "spmm": [{"row_tile": t, "col_tile": t} for t in (128, 256)],
@@ -138,8 +146,8 @@ def _save_disk(key: Tuple, params: Dict[str, int]) -> None:
             pass
 
 
-def sweep_enabled(backend: str) -> bool:
-    return backend == "tpu" or os.environ.get("REPRO_AUTOTUNE") == "1"
+def sweep_enabled() -> bool:
+    return os.environ.get("REPRO_AUTOTUNE") == "1"
 
 
 def candidates_for(kernel: str) -> List[Dict[str, int]]:
@@ -172,10 +180,12 @@ def sweep(
     ``make_thunk(params)`` returns a 0-arg callable running the kernel on
     representative inputs; it may raise to veto a candidate (e.g. a block
     larger than the problem).  Timing is min-over-repeats of a
-    block_until_ready'd call, after one warmup/compile call.
+    block_until_ready'd call, after one warmup/compile call.  Raises if
+    every candidate fails, chained to the last error.
     """
     best: Optional[Dict[str, int]] = None
     best_t = float("inf")
+    last_err: Optional[Exception] = None
     for params in candidates_for(kernel):
         try:
             thunk = make_thunk(params)
@@ -185,12 +195,15 @@ def sweep(
                 t0 = time.perf_counter()
                 jax.block_until_ready(thunk())
                 t = min(t, time.perf_counter() - t0)
-        except Exception:
-            continue  # candidate infeasible for this shape/backend
+        except Exception as e:  # candidate infeasible for this shape/backend
+            last_err = e
+            continue
         if t < best_t:
             best, best_t = dict(params), t
     if best is None:
-        best = dict(DEFAULTS[kernel])
+        raise RuntimeError(
+            f"autotune: every candidate of {kernel} failed for {_key_str(key)}"
+        ) from last_err
     _memo[key] = best
     _save_disk(key, best)
     return best
@@ -201,12 +214,14 @@ def get_params(
     shape: Dict[str, int],
     sweep_fn: Optional[Callable[[Dict[str, int]], Callable[[], object]]] = None,
     backend: Optional[str] = None,
+    traced: bool = False,
 ) -> Dict[str, int]:
     """The dispatch entry point: winner for (kernel, backend, bucket).
 
-    Order: process memo -> on-disk table -> sweep (if enabled and a
-    ``sweep_fn`` is given) -> ``DEFAULTS``.  Exactly one cold consult per
-    key; everything after is a memo hit.
+    Order: process memo -> on-disk table -> sweep (if enabled, a
+    ``sweep_fn`` is given and dispatch is not ``traced``) -> ``DEFAULTS``.
+    Exactly one cold consult per key; everything after is a memo hit.  A
+    traced miss that a later eager dispatch could sweep is not memoised.
     """
     backend = backend or jax.default_backend()
     key = cache_key(kernel, backend, shape)
@@ -215,9 +230,12 @@ def get_params(
         return hit
     CONSULTS[key] += 1
     params = _load_disk().get(_key_str(key))
-    if params is None and sweep_fn is not None and sweep_enabled(backend):
+    can_sweep = sweep_fn is not None and sweep_enabled()
+    if params is None and can_sweep and not traced:
         return sweep(kernel, sweep_fn, key)
     if params is None:
         params = dict(DEFAULTS[kernel])
+        if can_sweep:
+            return params
     _memo[key] = params
     return params

@@ -1,8 +1,9 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Handles: interpret-mode selection (CPU container -> interpret=True; real
-TPU -> compiled), padding to block multiples, and the ragged->padded
-layout conversions the kernels require.  Models and the Aspen flat level
+Handles: interpret-mode selection (CPU -> interpret=True; TPU ->
+compiled; any other backend is an error, never a silent interpreter),
+padding to block multiples, and the ragged->padded layout conversions
+the kernels require.  Models and the Aspen flat level
 call these, never pl.pallas_call directly.
 """
 from __future__ import annotations
@@ -17,8 +18,21 @@ from . import autotune, csr_spmm, delta_decode, flash_decode, segment_reduce
 
 
 def _interpret() -> bool:
-    """Pallas interpret mode unless running on real TPU hardware."""
-    return jax.default_backend() != "tpu"
+    """Pallas interpret mode on the CPU backend, compiled kernels on TPU.
+
+    Any other backend raises: running the interpreter there would hide
+    the device behind an emulator."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"no Pallas kernel path for backend {backend!r}")
+
+
+def _traced(x) -> bool:
+    """True while dispatch runs under a trace (autotune must not sweep)."""
+    return isinstance(x, jax.core.Tracer)
 
 
 def _gather_hi(deltas: jax.Array, hi: jax.Array | None, wide: jax.Array | None):
@@ -35,7 +49,7 @@ def _gather_hi(deltas: jax.Array, hi: jax.Array | None, wide: jax.Array | None):
     H = hi.shape[-2]
     if H == 0:
         return jnp.zeros_like(deltas, dtype=jnp.int8)
-    idx = jnp.clip(jnp.cumsum(wide.astype(jnp.int32)) - 1, 0, H - 1)
+    idx = jnp.clip(jnp.cumsum(wide, dtype=jnp.int32) - 1, 0, H - 1)
     return jnp.where(wide[:, None], hi[idx], jnp.int8(0))
 
 
@@ -189,7 +203,9 @@ def segment_sum(
     E = dst.shape[0]
     if edge_block is None or dst_block is None:
         kernel, make = _sweep_segment_sum(E, n_out, weighted=False)
-        tuned = autotune.get_params("segment_sum", {"E": E, "n": n_out}, sweep_fn=make)
+        tuned = autotune.get_params(
+            "segment_sum", {"E": E, "n": n_out}, sweep_fn=make, traced=_traced(msg)
+        )
         edge_block = edge_block or tuned["edge_block"]
         dst_block = dst_block or tuned["dst_block"]
     n_pad = n_out + (-n_out) % dst_block
@@ -219,7 +235,8 @@ def segment_sum_weighted(
     if edge_block is None or dst_block is None:
         _, make = _sweep_segment_sum(E, n_out, weighted=True)
         tuned = autotune.get_params(
-            "segment_sum_weighted", {"E": E, "n": n_out}, sweep_fn=make
+            "segment_sum_weighted", {"E": E, "n": n_out}, sweep_fn=make,
+            traced=_traced(msg),
         )
         edge_block = edge_block or tuned["edge_block"]
         dst_block = dst_block or tuned["dst_block"]
@@ -247,7 +264,7 @@ def _pad_chunked_dst(
     extra DST_BLOCK swallows them identically.  Adaptive streams
     additionally carry the pre-gathered hi plane and the wide tag; pad
     rows are narrow (wide=0, hi=0), decoding identically to fixed pads."""
-    edge_block = edge_block or segment_reduce.EDGE_BLOCK
+    edge_block = edge_block or segment_reduce.CHUNK_EDGE_BLOCK
     dst_block = dst_block or segment_reduce.DST_BLOCK
     R, C = deltas.shape
     rpb = edge_block // C
@@ -317,7 +334,8 @@ def segment_sum_chunked(
     if edge_block is None or dst_block is None:
         make = _sweep_segment_sum_chunked(R, C, n_out, False, hi is not None)
         tuned = autotune.get_params(
-            "segment_sum_chunked", {"R": R, "n": n_out}, sweep_fn=make
+            "segment_sum_chunked", {"R": R, "n": n_out}, sweep_fn=make,
+            traced=_traced(msg),
         )
         edge_block = edge_block or tuned["edge_block"]
         dst_block = dst_block or tuned["dst_block"]
@@ -357,7 +375,8 @@ def segment_sum_weighted_chunked(
     if edge_block is None or dst_block is None:
         make = _sweep_segment_sum_chunked(R, C, n_out, True, hi is not None)
         tuned = autotune.get_params(
-            "segment_sum_weighted_chunked", {"R": R, "n": n_out}, sweep_fn=make
+            "segment_sum_weighted_chunked", {"R": R, "n": n_out}, sweep_fn=make,
+            traced=_traced(msg),
         )
         edge_block = edge_block or tuned["edge_block"]
         dst_block = dst_block or tuned["dst_block"]
@@ -434,7 +453,9 @@ def spmm_from_edges(
 ):
     if row_tile is None or col_tile is None:
         m = int(np.asarray(src).shape[0])
-        tuned = autotune.get_params("spmm", {"n": n, "m": m}, sweep_fn=_sweep_spmm(n, m))
+        tuned = autotune.get_params(
+            "spmm", {"n": n, "m": m}, sweep_fn=_sweep_spmm(n, m), traced=_traced(x)
+        )
         row_tile = row_tile or tuned["row_tile"]
         col_tile = col_tile or tuned["col_tile"]
     mask, tiles, n_pad = csr_spmm.tiles_from_edges(

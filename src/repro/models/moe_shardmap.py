@@ -30,13 +30,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.6: top-level shard_map, replication check kwarg check_vma
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-except AttributeError:  # 0.4.x: experimental namespace, kwarg check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
+from repro.core.sharded_pool import shard_map
 
-    _CHECK_KW = "check_rep"
 
 # set by launch/cells.py before tracing (mesh objects cannot live in a
 # hashable LMConfig)
@@ -125,11 +120,10 @@ def moe_apply_shardmap(params: Dict[str, Any], cfg, x: jax.Array, mesh: Mesh) ->
         P("model", None, None),  # w_down
         (jax.tree.map(lambda _: P(None, None), shared) if shared is not None else None),
     )
-    fn = _shard_map(
+    fn = shard_map(
         local, mesh=mesh,
         in_specs=in_specs,
         out_specs=P(data_axes, None, None),
-        **{_CHECK_KW: False},
     )
     return fn(x, params["router"], params["w_gate"], params["w_up"],
               params["w_down"], shared)

@@ -104,6 +104,11 @@ class ResultCache:
         self.promoted_incremental = 0
         self.promoted_full = 0
         self.promoted_dropped = 0
+        # promotions that raised (each also counts as dropped): the entry
+        # degrades to a cold miss, so answers stay right, but a non-zero
+        # count means the carry-forward path is broken on this backend
+        self.promotion_errors = 0
+        self.last_promotion_error: Optional[str] = None
 
     # -- request path --------------------------------------------------------
     def get(self, v, kind: str, pkey: Tuple, source) -> Optional[CacheEntry]:
@@ -185,11 +190,13 @@ class ResultCache:
             return out
 
     def carry_forward(self, stream, v_old, v_new, backend: str,
-                      limit: int = 32) -> int:
+                      limit: int = 32, batch: int = PROMOTE_BATCH) -> int:
         """Promote hot ``v_old`` entries onto ``v_new`` through the
         incremental paths (module docstring).  Runs on the service's
         promotion thread — never the writer's publish callback, whose
-        contract forbids compute.  Returns the number promoted."""
+        contract forbids compute.  ``batch`` caps one promotion dispatch
+        (the service passes its lane ceiling: a batched driver's memory
+        grows with the batch).  Returns the number promoted."""
         entries = self.promotable(v_old, limit)
         if not entries:
             return 0
@@ -211,7 +218,7 @@ class ResultCache:
                     self.promoted_full += 1
 
         # bfs/sssp promote as pow2-padded batched dispatches grouped by
-        # params — one driver replay per PROMOTE_BATCH entries, the same
+        # params — one driver replay per ``batch`` entries, the same
         # shape discipline as serving; cc/pagerank go one at a time
         groups: "OrderedDict[Tuple, List]" = OrderedDict()
         singles: List[Tuple[Tuple, CacheEntry]] = []
@@ -230,16 +237,17 @@ class ResultCache:
             if (kind == "sssp" and delta is not None and eng_old is None
                     and (eng_new.weighted or delta.has_deletions)):
                 eng_old = stream._engine_for(v_old, backend)
-            for i in range(0, len(grp), PROMOTE_BATCH):
-                chunk = grp[i:i + PROMOTE_BATCH]
+            for i in range(0, len(grp), batch):
+                chunk = grp[i:i + batch]
                 try:
                     results = _promote_batch(
                         eng_old, eng_new, kind, chunk, delta
                     )
-                except Exception:
+                except Exception as e:
                     # a failed promotion is a dropped chunk, never a
                     # wrong answer (the next request recomputes cold)
                     self.promoted_dropped += len(chunk)
+                    self.record_promotion_error(e)
                     continue
                 land(chunk, results)
 
@@ -248,11 +256,19 @@ class ResultCache:
                 res = _promote_one(
                     eng_new, kind, dict(pkey), source, ent, delta
                 )
-            except Exception:
+            except Exception as e:
                 self.promoted_dropped += 1
+                self.record_promotion_error(e)
                 continue
             land([((kind, pkey, source), ent)], [res])
         return promoted
+
+    def record_promotion_error(self, err: BaseException) -> None:
+        """Count a promotion that raised (also the service's failed
+        carry-forward rounds); ``snapshot`` reports the count and the
+        last error."""
+        self.promotion_errors += 1
+        self.last_promotion_error = f"{type(err).__name__}: {err}"
 
     # -- introspection -------------------------------------------------------
     def snapshot(self) -> dict:
@@ -268,6 +284,8 @@ class ResultCache:
                 "promoted_incremental": self.promoted_incremental,
                 "promoted_full": self.promoted_full,
                 "promoted_dropped": self.promoted_dropped,
+                "promotion_errors": self.promotion_errors,
+                "last_promotion_error": self.last_promotion_error,
             }
 
 
@@ -288,7 +306,7 @@ def _promote_batch(
     """Promote one chunk of same-(kind, params) bfs/sssp entries in a
     SINGLE batched dispatch, sources padded to the next power of two so
     promotion replays the warmed trace ladder (service._warm_promotion
-    covers 1..PROMOTE_BATCH).  Incremental when the delta supports it,
+    covers 1..its promotion batch).  Incremental when the delta supports it,
     batched full recompute otherwise; exact either way."""
     from repro.core.traversal import algorithms as talg
 

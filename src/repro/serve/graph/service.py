@@ -115,6 +115,9 @@ class GraphQueryService:
         self._cache = ResultCache(cache_capacity) if result_cache else None
         self._carry = bool(carry_forward) and result_cache
         self._carry_limit = int(carry_limit)
+        # one promotion dispatch never exceeds the lane ceiling, whose
+        # batched-driver memory is what max_batch was sized against
+        self._promote_batch = min(PROMOTE_BATCH, L.next_pow2(self.max_batch))
         self._fastpath = bool(fastpath)
         self._anchor = None  # the promotion thread's held previous version
 
@@ -257,16 +260,13 @@ class GraphQueryService:
         return ok
 
     def insert_edges(self, edges: np.ndarray, block: bool = True) -> int:
-        n = 0
-        for s, d in np.asarray(edges, dtype=np.int64).reshape(-1, 2):
-            n += bool(self.enqueue_update(int(s), int(d), block=block))
-        return n
+        """Queue a batch of edge inserts as one unit (one publish when it
+        fits in ``update_batch``); returns how many were queued."""
+        return self.updates.put_many(edges, block=block)
 
     def delete_edges(self, edges: np.ndarray, block: bool = True) -> int:
-        n = 0
-        for s, d in np.asarray(edges, dtype=np.int64).reshape(-1, 2):
-            n += bool(self.enqueue_update(int(s), int(d), delete=True, block=block))
-        return n
+        """``insert_edges`` for deletions."""
+        return self.updates.put_many(edges, delete=True, block=block)
 
     def _writer_loop(self) -> None:
         while not self._stop_writer.is_set():
@@ -330,10 +330,13 @@ class GraphQueryService:
         self._promoting = True
         try:
             self._cache.carry_forward(
-                self.stream, anchor, cur, self.backend, limit=self._carry_limit
+                self.stream, anchor, cur, self.backend,
+                limit=self._carry_limit, batch=self._promote_batch,
             )
-        except Exception:
-            pass  # a failed round degrades hot entries to cold misses
+        except Exception as e:
+            # a failed round degrades hot entries to cold misses; counted
+            # as cache.promotion_errors in stats()
+            self._cache.record_promotion_error(e)
         finally:
             self._anchor = cur
             self.stream.release(anchor)
@@ -791,7 +794,7 @@ class GraphQueryService:
 
         d = Delta(ins=np.asarray([[0, 0]], np.int64))
         sizes: List[int] = [1]
-        while sizes[-1] * 2 <= PROMOTE_BATCH:
+        while sizes[-1] * 2 <= self._promote_batch:
             sizes.append(sizes[-1] * 2)
         for b in sizes:
             srcs = [0] * b

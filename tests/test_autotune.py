@@ -122,10 +122,14 @@ def test_sweep_all_vetoed_falls_back_to_defaults(monkeypatch):
     def make(params):
         raise ValueError("nope")
 
-    p = autotune.get_params(
-        "segment_sum", {"E": 128, "n": 64}, sweep_fn=make, backend="cpu"
-    )
-    assert p == autotune.DEFAULTS["segment_sum"]
+    # a sweep that finds no working candidate is an error, not a silent
+    # return of the defaults; the last candidate error is chained
+    with pytest.raises(RuntimeError, match="every candidate") as info:
+        autotune.get_params(
+            "segment_sum", {"E": 128, "n": 64}, sweep_fn=make, backend="cpu"
+        )
+    assert isinstance(info.value.__cause__, ValueError)
+    assert autotune._memo == {}  # nothing recorded
 
 
 # -- on-disk table -----------------------------------------------------------

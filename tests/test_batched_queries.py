@@ -278,3 +278,35 @@ def test_run_concurrent_batched_throughput(rmat_graph):
     )
     assert stats.n_queries > 0 and stats.n_queries % len(srcs) == 0
     assert stats.queries_per_sec > 0
+
+
+@pytest.mark.parametrize("L", [8, 832, 5000])
+def test_blocked_scans_match_numpy(L):
+    """The drivers' scan primitives (blocked shifted-add scans, int32)
+    against numpy: prefix sum, nonzero, and segmented sum/min/max over
+    bounds with empty segments."""
+    from repro.core.traversal import jax_backend as jb
+
+    rng = np.random.default_rng(L)
+    x = rng.integers(-5, 5, (3, L)).astype(np.int32)
+    np.testing.assert_array_equal(np.asarray(jb._cumsum(jnp.asarray(x))), np.cumsum(x, axis=1))
+    mask = rng.random(L) < 0.3
+    for size in (7, L):
+        got = np.asarray(jb._nonzero_i32(jnp.asarray(mask), size, L))
+        want = np.full(size, L)
+        nz = np.flatnonzero(mask)[:size]
+        want[: nz.size] = nz
+        np.testing.assert_array_equal(got, want)
+    bounds = np.sort(rng.integers(0, L, 11)).astype(np.int32)
+    bounds[0], bounds[-1] = 0, L
+    bounds[3] = bounds[4]  # an empty segment
+    msg = rng.integers(0, 9, (2, L)).astype(np.int32)
+    segs = list(zip(bounds[:-1], bounds[1:]))
+    b = jnp.asarray(bounds)
+    want = [[m[a:e].sum() for a, e in segs] for m in msg]
+    np.testing.assert_array_equal(np.asarray(jb._segsum_rows(jnp.asarray(msg), b)), want)
+    want = [[m[a:e].max() if e > a else -1 for a, e in segs] for m in msg]
+    np.testing.assert_array_equal(np.asarray(jb._segmax_rows(jnp.asarray(msg), b)), want)
+    f = msg.astype(np.float32)
+    want = [[m[a:e].min() if e > a else np.inf for a, e in segs] for m in f]
+    np.testing.assert_array_equal(np.asarray(jb._segmin_rows(jnp.asarray(f), b)), want)

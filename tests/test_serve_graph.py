@@ -473,3 +473,32 @@ def test_service_under_live_writer(rmat_edge_list):
     assert st["admission"]["in_flight"] == 0 and st["admission"]["backlog"] == 0
     done = sum(v["completed"] for v in st["tenants"].values())
     assert done == 60
+
+
+def test_batch_update_is_one_publish(rmat_edge_list):
+    """A batch handed to insert_edges / delete_edges reaches the writer as
+    one unit: one publish each (a split batch would publish odd-sized
+    pieces, each a new compiled merge shape on the device)."""
+    stream, svc = make_service(rmat_edge_list, backend="numpy", update_batch=4096)
+    batch = np.stack([np.arange(40, 80), np.arange(140, 180)], axis=1)
+    with svc:
+        stamp0 = stream.vg.current_stamp
+        assert svc.insert_edges(batch) == len(batch)
+        svc.flush_updates()
+        assert stream.vg.current_stamp == stamp0 + 1
+        assert svc.delete_edges(batch[:10]) == 10
+        svc.flush_updates()
+        assert stream.vg.current_stamp == stamp0 + 2
+    snap = stream.flat_snapshot()
+    assert all(int(d) in snap.neighbors(int(s)) for s, d in batch[10:])
+    assert all(int(d) not in snap.neighbors(int(s)) for s, d in batch[:10])
+
+
+def test_put_many_rejects_a_piece_that_finds_no_room():
+    from repro.core.streaming import UpdateQueue
+
+    q = UpdateQueue(maxsize=4)
+    assert q.put_many(np.array([[1, 2], [3, 4], [5, 6]])) == 3
+    assert q.put_many(np.array([[7, 8], [9, 10]]), block=False) == 0
+    st = q.stats()
+    assert (st["depth"], st["rejected"], st["enqueued"]) == (3, 2, 3)
