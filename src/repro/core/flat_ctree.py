@@ -124,15 +124,17 @@ def from_device(values: jax.Array, cap: int, vals: jax.Array | None = None) -> F
     this is the streaming ingest path (batches arrive device-resident
     and stay there).  ``vals`` rides along through a stable argsort, so
     the first occurrence of a duplicate key keeps its value (matching
-    ``from_array``)."""
-    if vals is None:
-        v = jnp.sort(values.ravel())
+    ``from_array``).  Its operations carry the device scope
+    ``merge.batch`` (the write path's batch sort and dedup)."""
+    with jax.named_scope("merge.batch"):
+        if vals is None:
+            v = jnp.sort(values.ravel())
+            keep = _dedup_mask(v, jnp.int32(v.shape[0]))
+            return _compact(v, keep, cap)
+        order = jnp.argsort(values.ravel(), stable=True)
+        v = values.ravel()[order]
         keep = _dedup_mask(v, jnp.int32(v.shape[0]))
-        return _compact(v, keep, cap)
-    order = jnp.argsort(values.ravel(), stable=True)
-    v = values.ravel()[order]
-    keep = _dedup_mask(v, jnp.int32(v.shape[0]))
-    return _compact(v, keep, cap, vals=vals.ravel()[order])
+        return _compact(v, keep, cap, vals=vals.ravel()[order])
 
 
 # ---------------------------------------------------------------------------
@@ -256,54 +258,62 @@ def union_merge(t: FlatCTree, batch: FlatCTree, out_cap: int) -> FlatCTree:
     of a kept b-element = #a-below + #kept-b-below.  Two searchsorteds and
     one scatter — bandwidth-bound, no sort network.  This mirrors the
     paper's Union leaf case (merge two chunks) applied to the whole pool.
+    Device scopes: ``merge.rank`` (the searchsorteds and prefix sums
+    that place every element) and ``merge.scatter`` (the writes).
     """
     a, b = t.data, batch.data
     sent = sentinel_for(a.dtype)
     ca, cb = a.shape[0], b.shape[0]
-    valid_a = jnp.arange(ca) < t.n
-    valid_b = jnp.arange(cb) < batch.n
+    with jax.named_scope("merge.rank"):
+        valid_a = jnp.arange(ca) < t.n
+        valid_b = jnp.arange(cb) < batch.n
 
-    # which b are duplicates of an a element?
-    ia = jnp.minimum(jnp.searchsorted(a, b), ca - 1)
-    dup_b = (a[ia] == b) & valid_b
-    keep_b = valid_b & ~dup_b
-    kb_excl = jnp.cumsum(keep_b, dtype=jnp.int32) - keep_b  # exclusive prefix
+        # which b are duplicates of an a element?
+        ia = jnp.minimum(jnp.searchsorted(a, b), ca - 1)
+        dup_b = (a[ia] == b) & valid_b
+        keep_b = valid_b & ~dup_b
+        kb_excl = jnp.cumsum(keep_b, dtype=jnp.int32) - keep_b  # exclusive prefix
 
-    # positions
-    ra = jnp.searchsorted(b, a)  # #b-entries < a[i] (valid b only: pad=max)
-    kept_below_a = jnp.where(ra > 0, kb_excl[jnp.minimum(ra - 1, cb - 1)] +
-                             keep_b[jnp.minimum(ra - 1, cb - 1)], 0)
-    pos_a = jnp.arange(ca, dtype=jnp.int32) + kept_below_a.astype(jnp.int32)
-    pos_a = jnp.where(valid_a, pos_a, out_cap)
+        # positions
+        ra = jnp.searchsorted(b, a)  # #b-entries < a[i] (valid b only: pad=max)
+        kept_below_a = jnp.where(ra > 0, kb_excl[jnp.minimum(ra - 1, cb - 1)] +
+                                 keep_b[jnp.minimum(ra - 1, cb - 1)], 0)
+        pos_a = jnp.arange(ca, dtype=jnp.int32) + kept_below_a.astype(jnp.int32)
+        pos_a = jnp.where(valid_a, pos_a, out_cap)
 
-    rb = jnp.searchsorted(a, b)  # #a < b[j]
-    pos_b = rb.astype(jnp.int32) + kb_excl.astype(jnp.int32)
-    pos_b = jnp.where(keep_b, pos_b, out_cap)
+        rb = jnp.searchsorted(a, b)  # #a < b[j]
+        pos_b = rb.astype(jnp.int32) + kb_excl.astype(jnp.int32)
+        pos_b = jnp.where(keep_b, pos_b, out_cap)
 
-    out = jnp.full((out_cap,), sent, dtype=a.dtype)
-    out = out.at[pos_a].set(a, mode="drop")
-    out = out.at[pos_b].set(b, mode="drop")
-    n_out = (t.n + keep_b.sum()).astype(jnp.int32)
-    va, vb = _aligned_vals(t, batch)
-    if va is None:
-        return FlatCTree(out, n_out)
-    # values ride the same two scatters; a duplicate b key lands its
-    # value on the matched a slot (insert overwrites, PaC-tree style)
-    vout = jnp.zeros((out_cap,), dtype=va.dtype)
-    vout = vout.at[pos_a].set(va, mode="drop")
-    vout = vout.at[pos_b].set(vb, mode="drop")
-    pos_dup = jnp.where(dup_b, pos_a[ia], out_cap)
-    vout = vout.at[pos_dup].set(vb, mode="drop")
-    return FlatCTree(out, n_out, vout)
+    with jax.named_scope("merge.scatter"):
+        out = jnp.full((out_cap,), sent, dtype=a.dtype)
+        out = out.at[pos_a].set(a, mode="drop")
+        out = out.at[pos_b].set(b, mode="drop")
+        n_out = (t.n + keep_b.sum()).astype(jnp.int32)
+        va, vb = _aligned_vals(t, batch)
+        if va is None:
+            return FlatCTree(out, n_out)
+        # values ride the same two scatters; a duplicate b key lands its
+        # value on the matched a slot (insert overwrites, PaC-tree style)
+        vout = jnp.zeros((out_cap,), dtype=va.dtype)
+        vout = vout.at[pos_a].set(va, mode="drop")
+        vout = vout.at[pos_b].set(vb, mode="drop")
+        pos_dup = jnp.where(dup_b, pos_a[ia], out_cap)
+        vout = vout.at[pos_dup].set(vb, mode="drop")
+        return FlatCTree(out, n_out, vout)
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
 def difference(t: FlatCTree, batch: FlatCTree, out_cap: int) -> FlatCTree:
     """MultiDelete: drop elements of t found in batch; compact (a
-    dropped key drops its associated value)."""
-    drop = member(batch, t.data)
-    valid = jnp.arange(t.data.shape[0]) < t.n
-    return _compact(t.data, valid & ~drop, out_cap, vals=t.vals)
+    dropped key drops its associated value).  Device scopes:
+    ``merge.rank`` (the membership search) and ``merge.scatter`` (the
+    compaction)."""
+    with jax.named_scope("merge.rank"):
+        drop = member(batch, t.data)
+        valid = jnp.arange(t.data.shape[0]) < t.n
+    with jax.named_scope("merge.scatter"):
+        return _compact(t.data, valid & ~drop, out_cap, vals=t.vals)
 
 
 @functools.partial(jax.jit, static_argnums=(2,))

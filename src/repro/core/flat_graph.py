@@ -171,9 +171,9 @@ def _insert_edges_impl(
     fn = fct.union_merge if optimized else fct.union_sort
     merged = fn(pool, batch, out_cap)
     n = g.offsets.shape[0] - 1 if n_out is None else n_out
-    return FlatGraph(
-        _offsets_from_keys(merged.data, merged.n, n), merged.data, merged.n, merged.vals
-    )
+    with jax.named_scope("merge.offsets"):
+        offsets = _offsets_from_keys(merged.data, merged.n, n)
+    return FlatGraph(offsets, merged.data, merged.n, merged.vals)
 
 
 def _delete_edges_impl(
@@ -182,7 +182,9 @@ def _delete_edges_impl(
     pool = fct.FlatCTree(g.keys, g.m, g.weights)
     out = fct.difference(pool, batch, out_cap)
     n = g.offsets.shape[0] - 1
-    return FlatGraph(_offsets_from_keys(out.data, out.n, n), out.data, out.n, out.vals)
+    with jax.named_scope("merge.offsets"):
+        offsets = _offsets_from_keys(out.data, out.n, n)
+    return FlatGraph(offsets, out.data, out.n, out.vals)
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3, 4))
@@ -197,7 +199,10 @@ def insert_edges(
 
     ``batch`` is a FlatCTree of packed keys (sorted, deduped, padded).
     ``n_out`` grows the vertex count (offsets array) when the batch
-    introduces vertex ids past the current range.
+    introduces vertex ids past the current range.  Device scopes:
+    ``merge.rank`` and ``merge.scatter`` (``fct.union_merge``), then
+    ``merge.offsets``; ``delete_edges`` has the same three
+    (``fct.difference``).
     """
     return _insert_edges_impl(g, batch, out_cap, optimized, n_out)
 

@@ -48,6 +48,7 @@ from collections import deque
 from typing import Callable, List, NamedTuple, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from . import graph as G
 from .versioning import DELTA, Delta, Version, VersionedGraph
@@ -544,9 +545,19 @@ class AspenStream:
             return m
         return self._mirror_from_tree(g2) if spilled else m
 
-    def _publish(self, tree_fn, mirror_fn, delta: Optional[Delta] = None) -> Version[G.Graph]:
+    def _publish(
+        self, tree_fn, mirror_fn, delta: Optional[Delta] = None, *, op: str, rows: int
+    ) -> Version[G.Graph]:
         """One writer transaction: update tree + mirror from the held
         version, publish both atomically as a single new version.
+
+        Profiler spans (``jax.profiler.TraceAnnotation``; recorded only
+        while a trace runs): ``aspen.publish`` (``op``, ``rows``: the
+        directed edge rows) around the whole transaction, lock wait and
+        listeners included; inside it ``aspen.publish.tree`` around the
+        host C-tree update and ``aspen.publish.mirror`` around the
+        mirror's batch packing, host->device copy and merge dispatch,
+        both with ``parent``, the stamp of the version they read.
 
         ``delta`` — the applied edge batch as a ``versioning.Delta`` —
         rides the published aux under ``versioning.DELTA``: the update
@@ -560,19 +571,24 @@ class AspenStream:
         rebuilt from the new tree instead of merged incrementally."""
 
         def txn(v: Version[G.Graph]):
-            g2 = tree_fn(v.graph)
+            with TraceAnnotation("aspen.publish.tree", parent=v.stamp):
+                g2 = tree_fn(v.graph)
             aux = {} if delta is None else {DELTA: delta}
             if self._mirror_enabled:
-                m = v.aux.get(self._mirror_kind)
-                m2 = (
-                    mirror_fn(m, v.graph, g2) if m is not None else self._mirror_from_tree(g2)
-                )
-                aux[self._mirror_kind] = self._heal_spill(m2, g2)
+                with TraceAnnotation("aspen.publish.mirror", parent=v.stamp):
+                    m = v.aux.get(self._mirror_kind)
+                    m2 = (
+                        mirror_fn(m, v.graph, g2)
+                        if m is not None
+                        else self._mirror_from_tree(g2)
+                    )
+                    aux[self._mirror_kind] = self._heal_spill(m2, g2)
             return g2, (aux or None)
 
-        with self._wlock:
-            v = self.vg.update_with_aux(txn)
-        self._notify_publish(v)
+        with TraceAnnotation("aspen.publish", op=op, rows=rows):
+            with self._wlock:
+                v = self.vg.update_with_aux(txn)
+            self._notify_publish(v)
         return v
 
     # -- update API (paper Appendix 10.4) ---------------------------------
@@ -603,6 +619,8 @@ class AspenStream:
             lambda g: G.insert_edges(g, edges, weights=weights),
             lambda m, g_old, g_new: self._apply_insert(m, g_old, edges, weights),
             delta=Delta(ins=edges, ins_w=weights),
+            op="insert",
+            rows=edges.shape[0],
         )
 
     def delete_edges(self, edges: np.ndarray, symmetric: bool = True):
@@ -613,6 +631,8 @@ class AspenStream:
             lambda g: G.delete_edges(g, edges),
             lambda m, g_old, g_new: self._apply_delete(m, edges),
             delta=Delta(dels=edges),
+            op="delete",
+            rows=edges.shape[0],
         )
 
     def rebalance(self):
@@ -631,7 +651,7 @@ class AspenStream:
                 )
             return sp.ShardedGraph(sp.rebalance(m.pool), m.n)
 
-        return self._publish(lambda g: g, mirror_fn, delta=Delta())
+        return self._publish(lambda g: g, mirror_fn, delta=Delta(), op="rebalance", rows=0)
 
     def insert_vertices(self, vs: np.ndarray):
         # vertex-set ops are control-plane-rare: the mirror takes the
@@ -639,12 +659,16 @@ class AspenStream:
         return self._publish(
             lambda g: G.insert_vertices(g, vs),
             lambda m, g_old, g_new: self._mirror_from_tree(g_new),
+            op="vertices",
+            rows=0,
         )
 
     def delete_vertices(self, vs: np.ndarray):
         return self._publish(
             lambda g: G.delete_vertices(g, vs),
             lambda m, g_old, g_new: self._mirror_from_tree(g_new),
+            op="vertices",
+            rows=0,
         )
 
     # -- read API -----------------------------------------------------------
@@ -759,12 +783,15 @@ class AspenStream:
         eng = v.cache.get(key)
         if eng is None:
             ENGINE_BUILDS.bump()
-            if backend == "jax" and MIRROR in v.aux:
-                eng = make_engine(v.aux[MIRROR])
-            elif backend == "sharded" and SHARDED_MIRROR in v.aux:
-                eng = make_engine(v.aux[SHARDED_MIRROR])
-            else:
-                eng = make_engine(G.flat_snapshot(v.graph), backend=backend)
+            # profiler span: a new version's engine refresh (engine_aux on
+            # the jax backend), on whichever thread first asks for it
+            with TraceAnnotation("aspen.engine_build", stamp=v.stamp):
+                if backend == "jax" and MIRROR in v.aux:
+                    eng = make_engine(v.aux[MIRROR])
+                elif backend == "sharded" and SHARDED_MIRROR in v.aux:
+                    eng = make_engine(v.aux[SHARDED_MIRROR])
+                else:
+                    eng = make_engine(G.flat_snapshot(v.graph), backend=backend)
             eng = v.cache.setdefault(key, eng)
         return eng
 

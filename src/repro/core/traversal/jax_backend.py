@@ -215,27 +215,40 @@ def _pool_endpoints(g: FlatGraph):
 
 @jax.jit
 def engine_aux(g: FlatGraph) -> EngineAux:
+    """Device scopes, one per phase: ``engine_aux.endpoints`` (unpack
+    and clip the pool), ``engine_aux.sort`` (the dst-major argsort),
+    ``engine_aux.permute`` (the gathers into dst-major order) and
+    ``engine_aux.offsets`` (degrees and the dst segment bounds)."""
     n = g.offsets.shape[0] - 1
-    src_c, dst_c, evalid = _pool_endpoints(g)
+    with jax.named_scope("engine_aux.endpoints"):
+        src_c, dst_c, evalid = _pool_endpoints(g)
     # dst-major permutation for the Pallas segment-sum and the batched
     # pull rounds (the pool is src-major): on-device sort-by-key
     # replaces the old host argsort.  valid => dst == dst_c, so the
     # clipped endpoints are exact here.
-    dst_key = jnp.where(evalid, dst_c, jnp.int32(n))
-    order = jnp.argsort(dst_key, stable=True)
-    dst_sorted = dst_key[order]
+    with jax.named_scope("engine_aux.sort"):
+        dst_key = jnp.where(evalid, dst_c, jnp.int32(n))
+        order = jnp.argsort(dst_key, stable=True)
+    with jax.named_scope("engine_aux.permute"):
+        dst_sorted = dst_key[order]
+        src_by_dst = src_c[order]
+        valid_by_dst = evalid[order]
+        w_by_dst = None if g.weights is None else g.weights[order]
+    with jax.named_scope("engine_aux.offsets"):
+        degrees = jnp.diff(g.offsets)
+        dst_offsets = jnp.searchsorted(
+            dst_sorted, jnp.arange(n + 1, dtype=jnp.int32)
+        ).astype(jnp.int32)
     return EngineAux(
         src_c=src_c,
         dst_c=dst_c,
         evalid=evalid,
-        degrees=jnp.diff(g.offsets),
+        degrees=degrees,
         dst_sorted=dst_sorted,
-        src_by_dst=src_c[order],
-        valid_by_dst=evalid[order],
-        dst_offsets=jnp.searchsorted(
-            dst_sorted, jnp.arange(n + 1, dtype=jnp.int32)
-        ).astype(jnp.int32),
-        w_by_dst=None if g.weights is None else g.weights[order],
+        src_by_dst=src_by_dst,
+        valid_by_dst=valid_by_dst,
+        dst_offsets=dst_offsets,
+        w_by_dst=w_by_dst,
     )
 
 

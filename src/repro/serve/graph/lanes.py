@@ -30,6 +30,7 @@ import time
 from typing import List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .metrics import LaneMetrics
 from .request import QueryTicket
@@ -167,7 +168,13 @@ def execute_batch(
     With ``cache``/``version`` set, every unique answer is also recorded
     on the serving version (the fill side of ``serve_cached``; bfs
     stashes its depths rows too — the warm state the carry-forward
-    ``incremental_bfs`` needs, computed for free by ``bfs_multi``)."""
+    ``incremental_bfs`` needs, computed for free by ``bfs_multi``).
+
+    Each driver call and the host copy of its result run inside a
+    ``serve.flush.fetch`` profiler span (``kind``): the driver's own
+    ``to_host`` is where the executor waits for the device, so the span
+    holds the dispatch, the wait behind earlier device work, the
+    programs themselves and the copy."""
     from repro.core.traversal import algorithms as talg
 
     now = time.perf_counter()
@@ -178,7 +185,8 @@ def execute_batch(
     pkey = tickets[0].pkey
 
     if kind == "cc":
-        labels = np.asarray(talg.connected_components(engine, **params), np.int64)
+        with TraceAnnotation("serve.flush.fetch", kind=kind):
+            labels = np.asarray(talg.connected_components(engine, **params), np.int64)
         if fill:
             cache.put(version, kind, pkey, None, labels)
         for t in tickets:
@@ -203,7 +211,8 @@ def execute_batch(
         # padding rows replay row 0 (a real row: no degenerate all-zero
         # reset reaches the driver)
         resets[b:, :] = resets[0, :]
-        scores = np.asarray(talg.pagerank_multi(engine, resets=resets, **params))
+        with TraceAnnotation("serve.flush.fetch", kind=kind):
+            scores = np.asarray(talg.pagerank_multi(engine, resets=resets, **params))
         if fill:
             for s, i in row_of.items():
                 cache.put(version, kind, pkey, s, scores[i])
@@ -214,14 +223,16 @@ def execute_batch(
     sources = np.asarray([t.source for t in tickets], dtype=np.int64)
     uniq, inv = np.unique(sources, return_inverse=True)
     if kind == "bfs":
-        rows, depths = talg.bfs_multi(engine, uniq, **params)
-        rows = np.asarray(rows, np.int64)
-        depths = np.asarray(depths, np.int64)
+        with TraceAnnotation("serve.flush.fetch", kind=kind):
+            rows, depths = talg.bfs_multi(engine, uniq, **params)
+            rows = np.asarray(rows, np.int64)
+            depths = np.asarray(depths, np.int64)
         if fill:
             for i, s in enumerate(uniq):
                 cache.put(version, kind, pkey, int(s), rows[i], state=depths[i])
     elif kind == "sssp":
-        rows = np.asarray(talg.sssp_multi(engine, uniq, **params), np.float64)
+        with TraceAnnotation("serve.flush.fetch", kind=kind):
+            rows = np.asarray(talg.sssp_multi(engine, uniq, **params), np.float64)
         if fill:
             for i, s in enumerate(uniq):
                 cache.put(version, kind, pkey, int(s), rows[i])

@@ -40,12 +40,15 @@ class QueryTicket:
     is the absolute SLO instant; ``deadline_missed`` is judged at
     completion time.  ``batch_size`` records how many requests rode the
     flush that served this ticket (the coalescing the bench reports).
+    ``seq`` is the service's submit counter (None outside ``submit``):
+    the number by which a ``serve.flush`` profiler span names its
+    tickets.
     """
 
     __slots__ = (
         "tenant", "kind", "source", "params", "pkey", "session",
         "deadline", "t_submit", "t_flush", "t_done", "batch_size",
-        "cached", "fastpath", "_event", "_result", "_error",
+        "cached", "fastpath", "seq", "_event", "_result", "_error",
     )
 
     def __init__(
@@ -74,6 +77,7 @@ class QueryTicket:
         self.batch_size: Optional[int] = None
         self.cached = False    # served from the result cache (batch_size 0)
         self.fastpath = False  # served at submit time, no lane/executor hop
+        self.seq: Optional[int] = None
         self._event = threading.Event()
         self._result = None
         self._error: Optional[BaseException] = None

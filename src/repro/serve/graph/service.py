@@ -46,6 +46,7 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.streaming import AspenStream, UpdateQueue, drain_updates
 from repro.core.traversal import TRACES
@@ -133,6 +134,7 @@ class GraphQueryService:
         self._sessions: set = set()
         self._warm = False
         self._publishes = 0
+        self._seq = 0  # submit counter, stamped on tickets as ``seq``
         self._unsubscribe = None
 
         self._running = False
@@ -329,10 +331,11 @@ class GraphQueryService:
             return
         self._promoting = True
         try:
-            self._cache.carry_forward(
-                self.stream, anchor, cur, self.backend,
-                limit=self._carry_limit, batch=self._promote_batch,
-            )
+            with TraceAnnotation("serve.promote", src=anchor.stamp, dst=cur.stamp):
+                self._cache.carry_forward(
+                    self.stream, anchor, cur, self.backend,
+                    limit=self._carry_limit, batch=self._promote_batch,
+                )
         except Exception as e:
             # a failed round degrades hot entries to cold misses; counted
             # as cache.promotion_errors in stats()
@@ -403,6 +406,8 @@ class GraphQueryService:
         with self._lock:
             if not self._running:
                 raise RuntimeError("service is not running")
+            ticket.seq = self._seq
+            self._seq += 1
             if self._cache is not None:
                 ent = self._cache_lookup_locked(ticket, session)
                 if ent is not None:
@@ -679,53 +684,63 @@ class GraphQueryService:
         """Executor job: pin the serving version (freshest or session),
         consult the result cache (flush-time dedup across time: hits
         drop out of the dispatch), note the trace key for the SHRUNK
-        batch, execute, then settle accounting."""
-        params = dict(batch[0].params)
-        v = None
-        n_cached = 0
-        error: Optional[BaseException] = None
-        try:
-            if lane.pin is not None:
-                ver = lane.pin.version
-            else:
-                v = self.stream.acquire()
-                ver = v
-            live = L.serve_cached(self._cache, ver, lane.kind, batch)
-            n_cached = len(batch) - len(live)
-            if live:
-                eng = self.stream._engine_for(ver, self.backend)
-                key = L.trace_key(
-                    lane.kind, eng, L.dispatch_pow2(lane.kind, live), lane.pkey
-                )
-                if key is not None:
-                    with self._lock:
-                        lane.metrics.record_trace_key(key, warm=self._warm)
-                L.execute_batch(
-                    eng, lane.kind, live, params,
-                    cache=self._cache, version=ver,
-                )
-        except BaseException as exc:  # noqa: BLE001 - fail the tickets, not the service
-            error = exc
-            for t in batch:
-                if not t.done():
-                    t._fail(exc)
-        finally:
-            if v is not None:
-                self.stream.release(v)
-            with self._lock:
-                self._active_flushes -= 1
-                lane.metrics.cache_hits += n_cached
+        batch, execute, then settle accounting.
+
+        The whole job is one ``serve.flush`` profiler span: ``kind``,
+        ``batch``, ``stamp`` (the version served) and ``tickets``, the
+        tickets' ``seq`` joined by spaces (the profiler's argument
+        encoding splits values at commas)."""
+        with TraceAnnotation(
+            "serve.flush", kind=lane.kind, batch=len(batch),
+            tickets=" ".join(str(t.seq) for t in batch),
+        ) as span:
+            params = dict(batch[0].params)
+            v = None
+            n_cached = 0
+            error: Optional[BaseException] = None
+            try:
+                if lane.pin is not None:
+                    ver = lane.pin.version
+                else:
+                    v = self.stream.acquire()
+                    ver = v
+                span.set_metadata(stamp=ver.stamp)
+                live = L.serve_cached(self._cache, ver, lane.kind, batch)
+                n_cached = len(batch) - len(live)
+                if live:
+                    eng = self.stream._engine_for(ver, self.backend)
+                    key = L.trace_key(
+                        lane.kind, eng, L.dispatch_pow2(lane.kind, live), lane.pkey
+                    )
+                    if key is not None:
+                        with self._lock:
+                            lane.metrics.record_trace_key(key, warm=self._warm)
+                    L.execute_batch(
+                        eng, lane.kind, live, params,
+                        cache=self._cache, version=ver,
+                    )
+            except BaseException as exc:  # noqa: BLE001 - fail the tickets, not the service
+                error = exc
                 for t in batch:
-                    self._admission.complete(t)
-                    if error is None and t.deadline_missed:
-                        lane.metrics.deadline_misses += 1
-                if error is not None:
-                    lane.metrics.errors += len(batch)
-                self._idle.notify_all()
-            for t in batch:
-                if t.session is not None:
-                    t.session._query_done(t)
-            self._wake.set()
+                    if not t.done():
+                        t._fail(exc)
+            finally:
+                if v is not None:
+                    self.stream.release(v)
+                with self._lock:
+                    self._active_flushes -= 1
+                    lane.metrics.cache_hits += n_cached
+                    for t in batch:
+                        self._admission.complete(t)
+                        if error is None and t.deadline_missed:
+                            lane.metrics.deadline_misses += 1
+                    if error is not None:
+                        lane.metrics.errors += len(batch)
+                    self._idle.notify_all()
+                for t in batch:
+                    if t.session is not None:
+                        t.session._query_done(t)
+                self._wake.set()
 
     def wait_idle(self, timeout: float = 30.0) -> None:
         """Block until no queued or in-flight queries remain."""

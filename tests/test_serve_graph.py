@@ -97,6 +97,21 @@ def test_duplicate_sources_one_compute_fan_out(rmat_edge_list):
         assert np.array_equal(r, ref)
 
 
+def test_ticket_seq_grows_by_one_per_submit(rmat_edge_list):
+    # every submit is numbered, cache hits included: the number a
+    # serve.flush profiler span names its tickets by
+    stream, svc = make_service(rmat_edge_list, backend="numpy")
+    with svc:
+        first = svc.submit("bfs", source=3)
+        first.result(timeout=30)
+        later = [svc.submit(k, source=s) for k, s in
+                 [("bfs", 3), ("bfs", 5), ("pagerank", 5), ("sssp", 9)]]
+        for t in later:
+            t.result(timeout=30)
+    assert later[0].cached  # an exact hit, served at submit
+    assert [t.seq for t in [first] + later] == list(range(5))
+
+
 def test_ticket_validation():
     stream, svc = make_service(symmetrize(rmat_edges(8, 2000, seed=11)))
     with svc:
