@@ -11,10 +11,16 @@ over a sorted pool — survives intact in flat form:
 
 All operations are fixed-shape jax ops: ``find`` is a searchsorted;
 ``union`` is either a concat-sort (baseline) or an O(n+k) rank-merge
-(optimized; two searchsorteds + scatter — the TPU analogue of the paper's
-leaf-level chunk merge); ``difference``/``intersect`` are membership masks
-+ compaction.  Chunk compression (fixed-width packed deltas, the vbyte
-adaptation) lives in ``chunks.pack_deltas`` for storage accounting and
+(optimized — the TPU analogue of the paper's leaf-level chunk merge);
+``difference`` is its mirror image, ``intersect`` a membership mask +
+compaction.  The rank-merge searches only the k batch rows (one binary
+search each), turns their ranks into per-slot shifts of the pool (a
+k-row histogram and one prefix sum), moves the pool slots by their
+shifts in log2(k)+1 rounds of a static shift and a select, and writes
+the batch rows with one k-row scatter: the pool is only ever streamed,
+never searched, gathered or scattered at its own length.  Chunk
+compression (fixed-width packed deltas, the vbyte adaptation) lives in
+``chunks.pack_deltas`` for storage accounting and
 ``kernels/delta_decode`` for the on-device decode.
 
 Capacity is static per jit trace; the host quantizes capacities to powers
@@ -250,70 +256,152 @@ def union_sort(t: FlatCTree, batch: FlatCTree, out_cap: int) -> FlatCTree:
     return _compact(allv, keep, out_cap, vals=vals)
 
 
+def _fit(x: jax.Array, length: int, fill) -> jax.Array:
+    """``x`` cut or padded with ``fill`` to a static length."""
+    if length <= x.shape[0]:
+        return x[:length]
+    return jnp.concatenate([x, jnp.full((length - x.shape[0],), fill, x.dtype)])
+
+
+def _shift(x: jax.Array, d: int, fill) -> jax.Array:
+    """Static shift by ``d`` slots, right for ``d > 0`` and left for
+    ``d < 0``: ``y[j] = x[j - d]``, and the slots shifted in hold
+    ``fill``."""
+    pad = jnp.full((abs(d),), fill, x.dtype)
+    return jnp.concatenate([pad, x[:-d]]) if d > 0 else jnp.concatenate([x[-d:], pad])
+
+
+def _place(
+    s: jax.Array, lanes: Tuple[jax.Array, ...], max_shift: int, right: bool
+) -> Tuple[jax.Array, Tuple[jax.Array, ...]]:
+    """Move each slot ``j`` with ``s[j] >= 0`` by ``s[j]`` slots (right or
+    left); ``s[j] == -1`` marks an empty slot.  One round per bit of
+    ``max_shift``, each a static shift by 2^k and a select over every
+    lane.  Right shifts that are non-decreasing over the occupied slots
+    take the high bit first, left shifts that are non-decreasing take
+    the low bit first: either way the partial positions stay strictly
+    increasing, so no two slots ever meet.  A slot whose target lies
+    outside the ``L`` slots drops, as in a scatter with ``mode="drop"``.
+    Returns the shifts at the targets (-1 where empty) and the moved
+    lanes (stale where empty)."""
+    L = s.shape[0]
+    j = jnp.arange(L, dtype=s.dtype)
+    target = j + s if right else j - s
+    s = jnp.where((target >= 0) & (target < L), s, -1)
+    bits = [1 << k for k in range(max_shift.bit_length()) if (1 << k) < L]
+    for d in (reversed(bits) if right else bits):
+        mv = (s >= 0) & ((s & d) != 0)
+        dd = d if right else -d
+        arrive = _shift(mv, dd, False)
+        s = jnp.where(arrive, _shift(s, dd, -1), jnp.where(mv, -1, s))
+        lanes = tuple(jnp.where(arrive, _shift(x, dd, 0), x) for x in lanes)
+    return s, lanes
+
+
+def merge_ranked(
+    t: FlatCTree, batch: FlatCTree, out_cap: int
+) -> Tuple[FlatCTree, jax.Array]:
+    """``union_merge`` plus the batch rows it kept (valid and not
+    already in ``t``), from which ``flat_graph`` updates CSR offsets."""
+    a, b = t.data, batch.data
+    sent = sentinel_for(a.dtype)
+    ca, cb = a.shape[0], b.shape[0]
+    va, vb = _aligned_vals(t, batch)
+    with jax.named_scope("merge.rank"):
+        # one binary search per batch row: #a < b[j], and whether b[j]
+        # duplicates the a element it lands on
+        rb = jnp.searchsorted(a, b).astype(jnp.int32)
+        ia = jnp.minimum(rb, ca - 1)
+        valid_b = jnp.arange(cb) < batch.n
+        dup_b = (a[ia] == b) & valid_b
+        keep_b = valid_b & ~dup_b
+        kb_excl = jnp.cumsum(keep_b, dtype=jnp.int32) - keep_b  # exclusive prefix
+        pos_b = jnp.where(keep_b, rb + kb_excl, out_cap)
+        # a[i] moves right by the number of kept b below it: a histogram
+        # of the kept rows' ranks, then one prefix sum over the pool
+        hist = jnp.zeros((ca,), jnp.int32).at[jnp.where(keep_b, rb, ca)].add(
+            1, mode="drop"
+        )
+        s = jnp.where(jnp.arange(ca) < t.n, jnp.cumsum(hist, dtype=jnp.int32), -1)
+
+    with jax.named_scope("merge.scatter"):
+        lanes = (_fit(a, out_cap, sent),)
+        if va is not None:
+            # a duplicate b key overwrites its matched a slot's value
+            # (insert overwrites, PaC-tree style) before the move
+            va = va.at[jnp.where(dup_b, ia, ca)].set(vb, mode="drop")
+            lanes += (_fit(va, out_cap, 0),)
+        s, lanes = _place(_fit(s, out_cap, -1), lanes, cb, right=True)
+        occupied = s >= 0
+        out = jnp.where(occupied, lanes[0], sent).at[pos_b].set(b, mode="drop")
+        n_out = (t.n + keep_b.sum()).astype(jnp.int32)
+        if va is None:
+            return FlatCTree(out, n_out), keep_b
+        vout = jnp.where(occupied, lanes[1], 0)
+        vout = vout.at[pos_b].set(vb, mode="drop")
+        return FlatCTree(out, n_out, vout), keep_b
+
+
 @functools.partial(jax.jit, static_argnums=(2,))
 def union_merge(t: FlatCTree, batch: FlatCTree, out_cap: int) -> FlatCTree:
-    """Optimized MultiInsert: O(n+k) rank-merge.
+    """Optimized MultiInsert: O(n+k) rank-merge by shifts.
 
-    Output position of a-element = own index + #unique-b-elements below it;
-    of a kept b-element = #a-below + #kept-b-below.  Two searchsorteds and
-    one scatter — bandwidth-bound, no sort network.  This mirrors the
-    paper's Union leaf case (merge two chunks) applied to the whole pool.
-    Device scopes: ``merge.rank`` (the searchsorteds and prefix sums
-    that place every element) and ``merge.scatter`` (the writes).
+    Output position of a-element = own index + #kept-b-elements below it;
+    of a kept b-element = #a-below + #kept-b-below.  The batch rows are
+    ranked by one binary search each (k searches, never one per pool
+    slot); the pool's shifts are a k-row histogram of those ranks and
+    one prefix sum over the pool.  The pool slots then move by their
+    shifts in log2(k)+1 rounds of a static shift and a select, and the
+    kept batch rows land in the gaps with one k-row scatter: every pass
+    over the pool streams, with no pool-length search, gather or
+    scatter.  This mirrors the paper's Union leaf case (merge two
+    chunks) applied to the whole pool.  Device scopes: ``merge.rank``
+    (the batch search and the shift counts) and ``merge.scatter`` (the
+    moves and the batch rows' write).
     """
+    return merge_ranked(t, batch, out_cap)[0]
+
+
+def difference_ranked(
+    t: FlatCTree, batch: FlatCTree, out_cap: int
+) -> Tuple[FlatCTree, jax.Array]:
+    """``difference`` plus the batch rows it found in ``t``, from which
+    ``flat_graph`` updates CSR offsets."""
     a, b = t.data, batch.data
     sent = sentinel_for(a.dtype)
     ca, cb = a.shape[0], b.shape[0]
     with jax.named_scope("merge.rank"):
-        valid_a = jnp.arange(ca) < t.n
-        valid_b = jnp.arange(cb) < batch.n
-
-        # which b are duplicates of an a element?
-        ia = jnp.minimum(jnp.searchsorted(a, b), ca - 1)
-        dup_b = (a[ia] == b) & valid_b
-        keep_b = valid_b & ~dup_b
-        kb_excl = jnp.cumsum(keep_b, dtype=jnp.int32) - keep_b  # exclusive prefix
-
-        # positions
-        ra = jnp.searchsorted(b, a)  # #b-entries < a[i] (valid b only: pad=max)
-        kept_below_a = jnp.where(ra > 0, kb_excl[jnp.minimum(ra - 1, cb - 1)] +
-                                 keep_b[jnp.minimum(ra - 1, cb - 1)], 0)
-        pos_a = jnp.arange(ca, dtype=jnp.int32) + kept_below_a.astype(jnp.int32)
-        pos_a = jnp.where(valid_a, pos_a, out_cap)
-
-        rb = jnp.searchsorted(a, b)  # #a < b[j]
-        pos_b = rb.astype(jnp.int32) + kb_excl.astype(jnp.int32)
-        pos_b = jnp.where(keep_b, pos_b, out_cap)
+        hit = jnp.searchsorted(a, b).astype(jnp.int32)
+        found = (a[jnp.minimum(hit, ca - 1)] == b) & (hit < t.n)
+        drop = jnp.zeros((ca,), bool).at[jnp.where(found, hit, ca)].set(
+            True, mode="drop"
+        )
+        # a kept a[i] moves left by the number of dropped slots below it
+        keep = (jnp.arange(ca) < t.n) & ~drop
+        s = jnp.where(keep, jnp.cumsum(drop, dtype=jnp.int32), -1)
 
     with jax.named_scope("merge.scatter"):
-        out = jnp.full((out_cap,), sent, dtype=a.dtype)
-        out = out.at[pos_a].set(a, mode="drop")
-        out = out.at[pos_b].set(b, mode="drop")
-        n_out = (t.n + keep_b.sum()).astype(jnp.int32)
-        va, vb = _aligned_vals(t, batch)
-        if va is None:
-            return FlatCTree(out, n_out)
-        # values ride the same two scatters; a duplicate b key lands its
-        # value on the matched a slot (insert overwrites, PaC-tree style)
-        vout = jnp.zeros((out_cap,), dtype=va.dtype)
-        vout = vout.at[pos_a].set(va, mode="drop")
-        vout = vout.at[pos_b].set(vb, mode="drop")
-        pos_dup = jnp.where(dup_b, pos_a[ia], out_cap)
-        vout = vout.at[pos_dup].set(vb, mode="drop")
-        return FlatCTree(out, n_out, vout)
+        lanes = (a,) if t.vals is None else (a, t.vals)
+        s, lanes = _place(s, lanes, cb, right=False)
+        occupied = _fit(s, out_cap, -1) >= 0
+        out = jnp.where(occupied, _fit(lanes[0], out_cap, sent), sent)
+        n_out = keep.sum().astype(jnp.int32)
+        if t.vals is None:
+            return FlatCTree(out, n_out), found
+        vout = jnp.where(occupied, _fit(lanes[1], out_cap, 0), 0)
+        return FlatCTree(out, n_out, vout), found
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
 def difference(t: FlatCTree, batch: FlatCTree, out_cap: int) -> FlatCTree:
     """MultiDelete: drop elements of t found in batch; compact (a
-    dropped key drops its associated value).  Device scopes:
-    ``merge.rank`` (the membership search) and ``merge.scatter`` (the
-    compaction)."""
-    with jax.named_scope("merge.rank"):
-        drop = member(batch, t.data)
-        valid = jnp.arange(t.data.shape[0]) < t.n
-    with jax.named_scope("merge.scatter"):
-        return _compact(t.data, valid & ~drop, out_cap, vals=t.vals)
+    dropped key drops its associated value).  One binary search per
+    batch row finds the dropped slots; the kept slots move left by the
+    dropped count below them (one prefix sum over the pool) in
+    log2(k)+1 rounds of a static shift and a select.  Device scopes:
+    ``merge.rank`` (the batch search and the shift counts) and
+    ``merge.scatter`` (the compaction)."""
+    return difference_ranked(t, batch, out_cap)[0]
 
 
 @functools.partial(jax.jit, static_argnums=(2,))

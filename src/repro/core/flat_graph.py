@@ -8,11 +8,15 @@ in-order traversal is the sorted pool, and headness is canonical, so the
 chunk boundaries (for delta compression) are recomputable by one hash
 pass (paper §3.1's key insight, vectorized).
 
-Batch updates are the flat C-tree rank-merge over packed keys followed by
-an O(n) offsets rebuild (one searchsorted).  On TPU this linear rebuild is
-*bandwidth-optimal* and beats pointer-chasing by orders of magnitude; the
+Batch updates are the flat C-tree rank-merge over packed keys (a binary
+search per batch row, then shifts of the pool: ``flat_ctree.union_merge``)
+and an update of the offsets from the batch rows the merge kept or
+dropped (a histogram of their sources and one prefix sum over the n+1
+offsets).  On TPU these streaming passes over the pool are
+*bandwidth-bound* and beat pointer-chasing by orders of magnitude; the
 paper's O(k log n) tree update is the CPU-optimal point of the same
-design space (DESIGN.md §2, §8).
+design space (DESIGN.md §2, §8).  The offsets update relies on every
+edge's src lying below n (``offsets[n] == m``).
 
 Everything here is fixed-shape jit: graphs carry static (n, edge_capacity)
 and a dynamic valid count, so the same compiled update/query step serves a
@@ -164,15 +168,36 @@ def chunk_structure(g: FlatGraph, b: int, seed: int):
 # ---------------------------------------------------------------------------
 
 
+def _src_counts_below(keys: jax.Array, rows: jax.Array, n: int) -> jax.Array:
+    """[n+1] count of the selected ``rows`` of ``keys`` whose src is below
+    each vertex id: a histogram over the rows, then one prefix sum."""
+    src = jnp.where(rows, keys >> 32, n + 1)
+    hist = jnp.zeros((n + 1,), jnp.int32).at[src].add(1, mode="drop")
+    return jnp.cumsum(hist, dtype=jnp.int32) - hist
+
+
+def _resized_offsets(offsets: jax.Array, n: int) -> jax.Array:
+    """Offsets over ``n`` vertices from offsets over ``offsets.shape[0]-1``:
+    vertices past the old range have no edges yet (every src < n)."""
+    n_old = offsets.shape[0] - 1
+    if n <= n_old:
+        return offsets[: n + 1]
+    return jnp.concatenate([offsets, jnp.full((n - n_old,), offsets[-1], offsets.dtype)])
+
+
 def _insert_edges_impl(
     g: FlatGraph, batch: fct.FlatCTree, out_cap: int, optimized: bool, n_out: int | None
 ) -> FlatGraph:
     pool = fct.FlatCTree(g.keys, g.m, g.weights)
-    fn = fct.union_merge if optimized else fct.union_sort
-    merged = fn(pool, batch, out_cap)
     n = g.offsets.shape[0] - 1 if n_out is None else n_out
+    if not optimized:  # the baseline: sort, then rebuild offsets by search
+        merged = fct.union_sort(pool, batch, out_cap)
+        with jax.named_scope("merge.offsets"):
+            offsets = _offsets_from_keys(merged.data, merged.n, n)
+        return FlatGraph(offsets, merged.data, merged.n, merged.vals)
+    merged, kept = fct.merge_ranked(pool, batch, out_cap)
     with jax.named_scope("merge.offsets"):
-        offsets = _offsets_from_keys(merged.data, merged.n, n)
+        offsets = _resized_offsets(g.offsets, n) + _src_counts_below(batch.data, kept, n)
     return FlatGraph(offsets, merged.data, merged.n, merged.vals)
 
 
@@ -180,10 +205,9 @@ def _delete_edges_impl(
     g: FlatGraph, batch: fct.FlatCTree, out_cap: int
 ) -> FlatGraph:
     pool = fct.FlatCTree(g.keys, g.m, g.weights)
-    out = fct.difference(pool, batch, out_cap)
-    n = g.offsets.shape[0] - 1
+    out, found = fct.difference_ranked(pool, batch, out_cap)
     with jax.named_scope("merge.offsets"):
-        offsets = _offsets_from_keys(out.data, out.n, n)
+        offsets = g.offsets - _src_counts_below(batch.data, found, g.offsets.shape[0] - 1)
     return FlatGraph(offsets, out.data, out.n, out.vals)
 
 
@@ -200,9 +224,12 @@ def insert_edges(
     ``batch`` is a FlatCTree of packed keys (sorted, deduped, padded).
     ``n_out`` grows the vertex count (offsets array) when the batch
     introduces vertex ids past the current range.  Device scopes:
-    ``merge.rank`` and ``merge.scatter`` (``fct.union_merge``), then
-    ``merge.offsets``; ``delete_edges`` has the same three
-    (``fct.difference``).
+    ``merge.rank`` and ``merge.scatter`` (``fct.merge_ranked``), then
+    ``merge.offsets`` (the old offsets plus the kept rows' sources below
+    each vertex); ``delete_edges`` has the same three
+    (``fct.difference_ranked``, the found rows' sources subtracted).
+    ``optimized=False`` is the baseline: ``fct.union_sort``, then the
+    offsets rebuilt by one search per vertex.
     """
     return _insert_edges_impl(g, batch, out_cap, optimized, n_out)
 

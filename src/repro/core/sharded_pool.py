@@ -1,8 +1,9 @@
 """Sharded flat C-tree pool: the beyond-paper distributed substrate.
 
 The baseline flat union (flat_ctree.union_merge) is a *global* rank-merge:
-under GSPMD, the cross-shard searchsorteds force all-gathers of the whole
-pool — collective-bound at pod scale (EXPERIMENTS.md §Perf baseline).
+under GSPMD, its batch search probes every shard and its pool-wide
+prefix sum and shifts cross shard boundaries — collective-bound at pod
+scale (EXPERIMENTS.md §Perf baseline).
 
 Here each device owns a contiguous KEY RANGE of the pool (range-sharded,
 like a distributed LSM level).  A batch update becomes:

@@ -105,3 +105,74 @@ def test_capacity_growth_policy():
     # powers of two quantize recompiles
     caps = {fct.grown_capacity(n) for n in range(1, 10000)}
     assert len(caps) <= 12
+
+
+# Edge cases of the rank-and-shift merge, each checked against union_sort
+# and a numpy reference.  A case: pool keys and capacity, batch keys and
+# capacity, the output capacity, and which side carries weights.
+_P = (np.arange(1, 21, dtype=np.int64) * 7) << 20  # 20 packed-size keys
+_MERGE_CASES = {
+    "empty_batch": (_P, 32, _P[:0], 8, 32, "both"),
+    "batch_all_duplicates": (_P, 32, _P[::3], 8, 32, "both"),
+    "absent_keys": (_P, 32, _P + 1, 32, 64, "pool"),
+    "batch_equals_pool": (_P, 32, _P, 32, 32, "both"),
+    "full_batch_all_new": (_P, 32, np.arange(8), 8, 32, "both"),
+    "full_batch_all_found": (_P, 32, _P[4:12], 8, 32, "pool"),
+    "pool_at_capacity": (_P[:16], 16, np.array([0, 5 << 20, 1 << 40]), 8, 32, "both"),
+    "batch_cap_above_pool_cap": (_P[:5], 8, _P[3:] + 3, 64, 64, "batch"),
+    "out_cap_above_pool_cap": (_P[:10], 16, _P[::2] - 1, 16, 128, "none"),
+    "out_cap_below_result": (_P, 32, _P[:12] + 5, 16, 16, "both"),
+    "unweighted": (_P, 32, np.arange(0, 300 << 20, 9 << 20), 64, 64, "none"),
+    "weighted": (_P, 32, np.arange(0, 300 << 20, 9 << 20), 64, 64, "both"),
+    "mixed_unweighted_pool": (_P, 32, _P[5:] - 7, 32, 64, "batch"),
+    "mixed_unweighted_batch": (_P, 32, _P[5:] - 7, 32, 64, "pool"),
+}
+
+
+def _tree(keys, cap, weighted, seed):
+    w = np.random.default_rng(seed).random(keys.size).astype(np.float32) + 2.0
+    return fct.from_array(keys, cap=cap, dtype=jnp.int64, vals=w if weighted else None)
+
+
+def _assert_tree(t, keys, weights):
+    cap = fct.capacity(t)
+    assert int(t.n) == keys.size
+    n = min(keys.size, cap)  # an overflowing result keeps its first cap keys
+    data = np.asarray(t.data)
+    np.testing.assert_array_equal(data[:n], keys[:n])
+    assert (data[n:] == fct.sentinel_for(data.dtype)).all()
+    if weights is None:
+        assert t.vals is None
+        return
+    vals = np.asarray(t.vals)
+    assert vals.shape == (cap,)
+    np.testing.assert_array_equal(vals[:n], weights[:n])
+    assert (vals[n:] == 0).all()
+
+
+@pytest.mark.parametrize("case", sorted(_MERGE_CASES))
+def test_merge_and_difference_edge_cases(case):
+    a, cap_a, b, cap_b, out_cap, weights = _MERGE_CASES[case]
+    ta = _tree(a, cap_a, weights in ("both", "pool"), 1)
+    tb = _tree(b, cap_b, weights in ("both", "batch"), 2)
+    wa = np.ones(a.size, np.float32) if ta.vals is None else fct.to_val_array(ta)
+    wb = np.ones(b.size, np.float32) if tb.vals is None else fct.to_val_array(tb)
+
+    # union: the batch's weight wins on a shared key; a side without
+    # weights counts as unit weights once the other side has them
+    keys = np.union1d(a, b)
+    by_key = dict(zip(a.tolist(), wa.tolist())) | dict(zip(b.tolist(), wb.tolist()))
+    w_union = None if weights == "none" else np.array([by_key[k] for k in keys.tolist()], np.float32)
+    merged = fct.union_merge(ta, tb, out_cap)
+    _assert_tree(merged, keys, w_union)
+    baseline = fct.union_sort(ta, tb, out_cap)
+    np.testing.assert_array_equal(np.asarray(merged.data), np.asarray(baseline.data))
+    assert int(merged.n) == int(baseline.n)
+    if w_union is not None:
+        np.testing.assert_array_equal(np.asarray(merged.vals), np.asarray(baseline.vals))
+
+    # difference: a dropped key drops its weight; the batch's weights
+    # play no part
+    kept = ~np.isin(a, b)
+    w_diff = None if ta.vals is None else wa[kept]
+    _assert_tree(fct.difference(ta, tb, out_cap), a[kept], w_diff)

@@ -217,3 +217,100 @@ def test_run_concurrent_engine_backend(small_graph):
     )
     assert stats.n_updates > 0 and stats.n_queries > 0
     assert_mirror_parity(s)
+
+
+@pytest.mark.parametrize("step", ["insert_grows_n", "delete", "donated"])
+def test_incremental_offsets_match_rebuild(small_graph, step):
+    """The merge updates CSR offsets from the batch rows alone; they must
+    equal a full rebuild by search over the merged pool."""
+    import jax.numpy as jnp
+
+    from repro.core import flat_ctree as fct
+
+    n, edges = small_graph
+    g = fg.from_edges(n, edges[:-300], fct.grown_capacity(edges.shape[0] + 64))
+    rows = np.concatenate([edges[-300:], edges[:100]])  # new edges and present ones
+    if step == "insert_grows_n":
+        rows = np.concatenate([rows, [[n + 3, 1], [n + 7, n + 2], [n + 7, 0]]])
+    keys = (rows[:, 0] << 32) | rows[:, 1]
+    batch = fct.from_device(jnp.asarray(keys), fct.grown_capacity(keys.size))
+    donate = step == "donated"
+    if step == "delete":
+        out = fg.delete_edges_device(g, batch)
+    else:
+        n_out = n + 8 if step == "insert_grows_n" else None
+        out = fg.insert_edges_device(g, batch, g.edge_capacity, n_out=n_out, donate=donate)
+        if donate:
+            out = fg.delete_edges_device(out, batch, donate=True)
+    np.testing.assert_array_equal(
+        np.asarray(out.offsets), np.asarray(fg._offsets_from_keys(out.keys, out.m, out.n))
+    )
+    assert int(out.offsets[-1]) == int(out.m)
+
+
+def _index_rows(var) -> int:
+    shape = var.aval.shape
+    return int(np.prod(shape[:-1])) if len(shape) > 1 else int(np.prod(shape))
+
+
+def _pool_length_work(closed, batch_cap: int) -> list:
+    """Operations of a program, sub-programs included, that scatter or
+    gather more index rows, or loop over more queries (a searchsorted's
+    carried bounds), than the batch has rows."""
+    from jax.extend import core as jcore
+
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            name = eqn.primitive.name
+            if name.startswith("scatter") or name == "gather":
+                rows = _index_rows(eqn.invars[1])
+                if rows > batch_cap:
+                    found.append(f"{name}: {rows} index rows")
+            elif name == "while":
+                carry = eqn.invars[eqn.params["cond_nconsts"] + eqn.params["body_nconsts"]:]
+                found.extend(f"while carry of {v.aval.size}" for v in carry
+                             if v.aval.size > batch_cap)
+            elif name == "scan":
+                k, c = eqn.params["num_consts"], eqn.params["num_carry"]
+                found.extend(f"scan carry of {v.aval.size}" for v in eqn.invars[k:k + c]
+                             if v.aval.size > batch_cap)
+            for p in eqn.params.values():
+                for sub in p if isinstance(p, (tuple, list)) else (p,):
+                    if isinstance(sub, jcore.ClosedJaxpr):
+                        walk(sub.jaxpr)
+                    elif isinstance(sub, jcore.Jaxpr):
+                        walk(sub)
+
+    walk(closed.jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("weighted", [False, True])
+@pytest.mark.parametrize("op", ["insert_edges", "delete_edges"])
+def test_merge_programs_do_no_pool_length_search_or_scatter(op, weighted):
+    """Regression guard: the insert and delete programs search only the
+    batch rows and scatter or gather at most that many rows; the pool is
+    only streamed.  The baseline's offsets rebuild (one search per
+    vertex) is what the guard exists to catch."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import flat_ctree as fct
+
+    cap, n, bcap = 4096, 512, 64
+    w = jax.ShapeDtypeStruct((cap,), jnp.float32) if weighted else None
+    g = fg.FlatGraph(jax.ShapeDtypeStruct((n + 1,), jnp.int32),
+                     jax.ShapeDtypeStruct((cap,), jnp.int64),
+                     jax.ShapeDtypeStruct((), jnp.int32), w)
+    bw = jax.ShapeDtypeStruct((bcap,), jnp.float32) if weighted else None
+    batch = fct.FlatCTree(jax.ShapeDtypeStruct((bcap,), jnp.int64),
+                          jax.ShapeDtypeStruct((), jnp.int32), bw)
+    if op == "insert_edges":
+        program = jax.make_jaxpr(fg.insert_edges, static_argnums=(2, 3, 4))
+        assert _pool_length_work(program(g, batch, cap, True, n + 64), bcap) == []
+        assert _pool_length_work(program(g, batch, cap, False, None), bcap)  # the guard bites
+    else:
+        program = jax.make_jaxpr(fg.delete_edges, static_argnums=(2,))
+        assert _pool_length_work(program(g, batch, cap), bcap) == []
